@@ -34,11 +34,11 @@ def bench_analysis_lint() -> List[Row]:
 
 def bench_analysis_audit() -> List[Row]:
     """One jaxpr audit of a configured (2,2) device reduce (trace only)."""
-    import jax
     import numpy as np
 
     from repro.analysis.auditor import audit_reduce
     from repro.core.api import SparseAllreduce
+    from repro.launch.mesh import make_mesh
 
     m = 4
     rng = np.random.RandomState(m)
@@ -47,7 +47,7 @@ def bench_analysis_audit() -> List[Row]:
     in_idx = [rng.choice(4096, rng.randint(5, 16),
                          replace=False).astype(np.uint32) for _ in range(m)]
     ar = SparseAllreduce(m, (2, 2), backend="device",
-                         mesh=jax.make_mesh((m,), ("d",)), seed=m)
+                         mesh=make_mesh((m,), ("d",)), seed=m)
     ar.config(out_idx, in_idx)
     audit_reduce(ar)                        # warm (first trace)
     reps = 3

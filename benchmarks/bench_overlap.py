@@ -117,13 +117,14 @@ def bench_overlap_sync_step() -> List[Row]:
     import jax
     import jax.numpy as jnp
 
+    from repro.launch.mesh import make_mesh
     from repro.models import transformer as T
     from repro.train.step import make_sync_fn
 
     if len(jax.devices()) < 8:
         return [("overlap/sync_step_skipped", 0.0, "needs 8 devices")]
     cfg = _tiny_cfg()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     params = T.init_params(cfg, 2, seed=0)
     rng = np.random.RandomState(0)
     grads = jax.tree.map(
@@ -164,12 +165,14 @@ def bench_overlap_engine() -> List[Row]:
     scan (parity documented, see module docstring)."""
     import jax
 
+    from repro.launch.mesh import make_mesh
+
     from repro.data.pipeline import powerlaw_graph
     from repro.graph.engine import GraphEngine
     from repro.graph.pagerank import build_partitions, make_pagerank_engine
 
     m = min(len(jax.devices()), 8)
-    mesh = jax.make_mesh((m,), ("d",))
+    mesh = make_mesh((m,), ("d",))
     edges = powerlaw_graph(2000, 12000, seed=1)
     parts = build_partitions(edges, 2000, m)
     base, extras, p0 = make_pagerank_engine(parts, 2000, degrees=(4, 2),

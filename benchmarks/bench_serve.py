@@ -33,14 +33,13 @@ SLO_STEPS = 64.0
 
 
 def _scheduler(dispatch=None):
-    import jax
-
     from repro.configs import get_config
+    from repro.launch.mesh import make_mesh
     from repro.models import transformer as T
     from repro.serve import ContinuousBatchingScheduler
 
     cfg = get_config("qwen1.5-0.5b").reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = T.init_params(cfg, tp=1, seed=0)
     sched = ContinuousBatchingScheduler(
         cfg, mesh, params, slots=SLOTS,

@@ -30,6 +30,8 @@ def main(argv=None) -> None:
                          "to run (default: all)")
     args = ap.parse_args(argv)
 
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     from benchmarks.bench_analysis import ALL_BENCHES as ANALYSIS_BENCHES
     from benchmarks.bench_autotune import ALL_BENCHES as AUTOTUNE_BENCHES
     from benchmarks.bench_fault_tolerance import ALL_BENCHES as FAULT_BENCHES

@@ -70,6 +70,7 @@ def _audit_sweep() -> List:
     import numpy as np
 
     from repro.core.api import SparseAllreduce
+    from repro.launch.mesh import make_mesh
     from .auditor import audit_engine, audit_reduce
 
     reports = []
@@ -84,7 +85,7 @@ def _audit_sweep() -> List:
                   for _ in range(m)]
         for r in (1, 2):
             ar = SparseAllreduce(m, degs, backend="device", replication=r,
-                                 mesh=jax.make_mesh((m * r,), ("d",)),
+                                 mesh=make_mesh((m * r,), ("d",)),
                                  seed=m)
             ar.config(out_idx, in_idx)
             reports.append(audit_reduce(ar))
@@ -95,7 +96,7 @@ def _audit_sweep() -> List:
     parts = build_partitions(edges, 300, _AUDIT_DEVICES)
     engine, extras, p0 = make_pagerank_engine(
         parts, 300, degrees=(4, 2),
-        mesh=jax.make_mesh((_AUDIT_DEVICES,), ("d",)))
+        mesh=make_mesh((_AUDIT_DEVICES,), ("d",)))
     reports.append(audit_engine(engine, 5, p0, extras))
 
     # overlap schedules: the double-buffered engine rotation and the
@@ -106,7 +107,7 @@ def _audit_sweep() -> List:
         [_np.asarray(o) for o in engine.out_sets],
         [_np.asarray(i) for i in engine.in_sets],
         engine.app, degrees=(4, 2),
-        mesh=jax.make_mesh((_AUDIT_DEVICES,), ("d",)), overlap=True)
+        mesh=make_mesh((_AUDIT_DEVICES,), ("d",)), overlap=True)
     reports.append(audit_engine(ov_engine, 5, p0, extras))
 
     import jax.numpy as jnp
@@ -117,7 +118,7 @@ def _audit_sweep() -> List:
                                       dense_allreduce_hierarchical_bucketed,
                                       make_device_plan)
     plan = make_device_plan([("d", _AUDIT_DEVICES)], {"d": (4, 2)}, 8, 8)
-    mesh = jax.make_mesh((_AUDIT_DEVICES,), ("d",))
+    mesh = make_mesh((_AUDIT_DEVICES,), ("d",))
     sizes = (64, 32, 96)
 
     def _mk(schedule):

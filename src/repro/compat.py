@@ -1,122 +1,21 @@
-"""JAX runtime-compatibility layer — the ONLY place version-sensitive
-symbols are resolved.
+"""The one import point for JAX symbols that have moved between releases.
 
-Two APIs moved across the JAX versions this repo supports (>= 0.4.30):
+The repo targets the one installed JAX (``jax>=0.9``), so these are plain
+re-exports:
 
-  * ``shard_map`` — lives at ``jax.experimental.shard_map.shard_map`` on
-    0.4.x (replication check kwarg: ``check_rep``) and was promoted to
-    ``jax.shard_map`` on newer releases (kwarg renamed to ``check_vma``).
-  * Pallas TPU compiler params — ``pltpu.TPUCompilerParams`` on 0.4.x,
-    renamed to ``pltpu.CompilerParams`` later.
+  * ``shard_map`` — ``jax.shard_map`` (replication check kwarg:
+    ``check_vma``).
+  * ``CompilerParams`` / ``PrefetchScalarGridSpec`` — the Pallas TPU
+    compiler params and scalar-prefetch grid spec (the banded kernels'
+    BlockSpec index maps read host-computed tables through it).
 
-Policy (see README "JAX compatibility"): every module under ``repro``
-imports these names from here — never from ``jax`` directly (enforced by
-``tests/test_compat.py::test_no_version_sensitive_imports_outside_compat``).
-To add a new shim: write a ``resolve_*`` pure function that takes the
-module(s) to probe (so both branches stay unit-testable against fakes),
-call it once at module scope below, and re-export the resolved name.
+Every module under ``repro`` imports these names from here, never from
+``jax`` directly (lint rules RA101/RA102 in ``repro.analysis.rules``), so a
+future move is a one-file change.
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Tuple
+from jax import shard_map
+from jax.experimental.pallas.tpu import CompilerParams, PrefetchScalarGridSpec
 
-import jax
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-def _check_kwarg_of(fn: Callable, default: str) -> str:
-    """Which replication-check kwarg ``fn`` takes (by signature, not by
-    where the symbol lives — some releases promoted ``jax.shard_map``
-    before renaming ``check_rep`` to ``check_vma``)."""
-    import inspect
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):
-        return default
-    if "check_vma" in params:
-        return "check_vma"
-    if "check_rep" in params:
-        return "check_rep"
-    return default
-
-
-def resolve_shard_map(jax_module: Any, experimental_module: Any = None
-                      ) -> Tuple[Callable, str]:
-    """Return ``(raw_shard_map, replication_check_kwarg_name)``.
-
-    Newer JAX exposes ``jax.shard_map``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map`` (kwarg ``check_rep``).
-    """
-    fn = getattr(jax_module, "shard_map", None)
-    if fn is not None:
-        return fn, _check_kwarg_of(fn, "check_vma")
-    if experimental_module is None:
-        from jax.experimental import shard_map as experimental_module
-    fn = experimental_module.shard_map
-    return fn, _check_kwarg_of(fn, "check_rep")
-
-
-def make_shard_map(raw: Callable, check_kwarg: str) -> Callable:
-    """Wrap a raw shard_map so call sites can always pass ``check_vma=``.
-
-    The wrapper translates ``check_vma`` to whatever replication-check
-    kwarg the resolved implementation actually takes and forwards
-    everything else untouched.
-    """
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-        if check_vma is not None:
-            kwargs.setdefault(check_kwarg, check_vma)
-        return raw(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   **kwargs)
-
-    return shard_map
-
-
-_RAW_SHARD_MAP, _CHECK_KWARG = resolve_shard_map(jax)
-shard_map = make_shard_map(_RAW_SHARD_MAP, _CHECK_KWARG)
-
-
-# ---------------------------------------------------------------------------
-# Pallas TPU compiler params
-# ---------------------------------------------------------------------------
-
-def resolve_compiler_params(pltpu_module: Any) -> Any:
-    """Pick ``CompilerParams`` (new name) or ``TPUCompilerParams`` (0.4.x)."""
-    cls = getattr(pltpu_module, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu_module.TPUCompilerParams
-    return cls
-
-
-from jax.experimental.pallas import tpu as _pltpu  # noqa: E402
-
-CompilerParams = resolve_compiler_params(_pltpu)
-
-
-# ---------------------------------------------------------------------------
-# Pallas scalar-prefetch grid spec
-# ---------------------------------------------------------------------------
-
-def resolve_prefetch_grid_spec(pltpu_module: Any) -> Any:
-    """``pltpu.PrefetchScalarGridSpec`` under its historical or promoted
-    name.  Scalar prefetch is what lets a kernel's BlockSpec index maps read
-    a host-computed table (the banded kernels' per-tile start blocks and
-    block-edge tables) before the body runs."""
-    for name in ("PrefetchScalarGridSpec", "PrefetchGridSpec"):
-        cls = getattr(pltpu_module, name, None)
-        if cls is not None:
-            return cls
-    raise ImportError(
-        "Pallas TPU module exposes no scalar-prefetch grid spec; banded "
-        "kernels need PrefetchScalarGridSpec (jax >= 0.4.30)")
-
-
-PrefetchScalarGridSpec = resolve_prefetch_grid_spec(_pltpu)
-
-__all__ = ["shard_map", "CompilerParams", "PrefetchScalarGridSpec",
-           "resolve_shard_map", "make_shard_map", "resolve_compiler_params",
-           "resolve_prefetch_grid_spec"]
+__all__ = ["shard_map", "CompilerParams", "PrefetchScalarGridSpec"]

@@ -210,6 +210,7 @@ class SparseAllreduce:
             from . import autotune
             from .allreduce import make_device_plan
             from .planned import plan_sparse_allreduce
+            from repro.launch.mesh import make_mesh
             mesh = self.mesh
             if mesh is None:
                 n = len(jax.devices())
@@ -217,7 +218,7 @@ class SparseAllreduce:
                     raise ValueError(
                         f"{n} devices for {m_phys} physical nodes "
                         f"({self.num_nodes} logical x r={r})")
-                mesh = jax.make_mesh((m_phys,), ("nodes",))
+                mesh = make_mesh((m_phys,), ("nodes",))
             axis = mesh.axis_names[0]
             self._mesh_used = mesh
             fp = autotune.planned_fingerprint(
@@ -384,6 +385,7 @@ class SparseAllreduce:
 
         from .allreduce import make_device_plan, run_union_allreduce
         from .replication import contribution_weights, first_alive_replicas
+        from repro.launch.mesh import make_mesh
         r, m_phys = self.replication, self.num_physical
         if r != 1 or self.dead:
             contribution_weights(m_phys, r, self.dead)  # DeadLogicalNode
@@ -405,7 +407,7 @@ class SparseAllreduce:
             self.union_plan_stats["misses"] += 1
             mesh = self.mesh
             if mesh is None:
-                mesh = jax.make_mesh((m_phys,), ("nodes",))
+                mesh = make_mesh((m_phys,), ("nodes",))
             axis = mesh.axis_names[0]
             dplan = make_device_plan(
                 [(axis, m_phys)], {axis: self.plan.degrees},

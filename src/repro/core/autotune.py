@@ -188,8 +188,9 @@ def measure_stage_samples(mesh=None, *, payload_entries=(256, 4096, 32768),
     from jax.sharding import PartitionSpec as P
 
     from repro.compat import shard_map
+    from repro.launch.mesh import make_mesh
     if mesh is None:
-        mesh = jax.make_mesh((len(jax.devices()),), ("nodes",))
+        mesh = make_mesh((len(jax.devices()),), ("nodes",))
     axis = mesh.axis_names[0]
     m = int(mesh.shape[axis])
     if degrees is None:

@@ -4,8 +4,8 @@ The paper's headline workloads are *iterative*: PageRank, HADI and spectral
 partitioning amortize one ``config`` over many ``reduce`` rounds.  The
 per-call device path (``SparseAllreduce.reduce``) still pays one host
 staging + one jitted dispatch per round; this module closes that gap by
-composing the **local SpMV** (the blocked ELL Pallas kernel,
-``repro.kernels.spmv_ell``) with the **planned sparse-allreduce reduce**
+composing the **local SpMV** (an ELL gather + row sum,
+:func:`ell_matvec`) with the **planned sparse-allreduce reduce**
 (``PlannedSparseAllreduce.reduce_on_device``) inside one jitted
 multi-iteration step:
 
@@ -45,7 +45,7 @@ from repro.core.netmodel import EC2_2013, Fabric
 
 
 # ---------------------------------------------------------------------------
-# Vectorized ELL construction (shared with Partition.spmv_ell)
+# Vectorized ELL construction (shared with Partition.ell_tables)
 # ---------------------------------------------------------------------------
 
 def build_ell(rows: np.ndarray, cols: np.ndarray, weights: np.ndarray,
@@ -92,18 +92,14 @@ def stack_ell(tables, n_rows: int) -> Tuple[np.ndarray, np.ndarray]:
     return cols, wts
 
 
-def ell_matvec(cols, wts, x, use_kernel: bool = False):
+def ell_matvec(cols, wts, x):
     """``y[r] = sum_k wts[r,k] * x[cols[r,k]]`` with ``cols < 0`` padding.
 
-    ``x``: [N] or [N, W] (per-device state).  With ``use_kernel=True`` and
-    1-D ``x`` the blocked ELL Pallas kernel (``repro.kernels.spmv_ell``)
-    runs — natively on TPU, interpret mode elsewhere; the jnp gather-sum
-    fallback (and the only W>1 path) computes the identical product.
+    ``x``: [N] or [N, W] (per-device state).  An XLA gather and a row sum;
+    the partition's ``x`` is far larger than a kernel's fast memory, so
+    there is no Pallas form of this product.
     """
     import jax.numpy as jnp
-    if use_kernel and x.ndim == 1:
-        from repro.kernels import ops
-        return ops.spmv(cols, wts, x)
     safe = jnp.maximum(cols, 0)
     g = x[safe]                                  # [R, K] or [R, K, W]
     mask = (cols >= 0).astype(x.dtype)
@@ -424,11 +420,16 @@ class GraphEngine:
         Compiled functions are cached per ``(k, collect)``; repeated calls
         with the same k re-dispatch without re-tracing.
         """
-        import jax.numpy as jnp
+        import jax
+        from jax.sharding import NamedSharding
+        from jax.sharding import PartitionSpec as P
         from jax.tree_util import tree_map
         fn = self.run_fn(k, collect)
-        state = tree_map(jnp.asarray, state)
-        extras = tree_map(jnp.asarray, extras if extras is not None else {})
+        # host arrays go straight to their shards: staged whole on one
+        # device first, the stacked ELL tables of M partitions do not fit
+        shard = NamedSharding(self.mesh, P(self.axis))
+        state, extras = jax.device_put(
+            (state, extras if extras is not None else {}), shard)
         final, last_out, traj = fn(state, extras, *self._routing)
         self.report["dispatches"] += 1
         self.report["rounds"] += k

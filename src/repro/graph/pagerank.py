@@ -5,8 +5,8 @@ node's outbound set = rows its edges write, inbound set = columns its edges
 read; ``config`` once (static graph), then per iteration
 ``in.values = reduce(out.values)`` + local SpMV.
 
-The local SpMV runs in numpy (simulator backend) or through the ELL Pallas
-kernel (``use_kernel=True``, interpret mode off-TPU).
+The local SpMV runs in numpy (simulator backend) or as the device engine's
+ELL gather-sum (``backend="device"``).
 """
 from __future__ import annotations
 
@@ -46,18 +46,6 @@ class Partition:
         w = self.inv_outdeg if weights is None else weights
         return build_ell(self.dst_pos, self.src_pos, w, len(self.out_idx))
 
-    def spmv_ell(self, in_values: np.ndarray, use_kernel: bool = True
-                 ) -> np.ndarray:
-        """Same product through the blocked ELL Pallas kernel."""
-        import jax.numpy as jnp
-        from repro.kernels import ops
-        if len(self.out_idx) == 0:
-            return np.zeros(0, np.float64)
-        cols, wts = self.ell_tables()
-        y = ops.spmv(jnp.asarray(cols), jnp.asarray(wts),
-                     jnp.asarray(in_values, jnp.float32))
-        return np.asarray(y, np.float64)
-
 
 def build_partitions(edges: np.ndarray, n_vertices: int, m: int,
                      seed: int = 0) -> List[Partition]:
@@ -79,7 +67,7 @@ def build_partitions(edges: np.ndarray, n_vertices: int, m: int,
 def pagerank(edges: np.ndarray, n_vertices: int, m: int,
              degrees=(4, 2), iters: int = 10, damping: float = 0.85,
              backend: str = "sim", fabric: Fabric = EC2_2013,
-             use_kernel: bool = False, seed: int = 0, mesh=None
+             seed: int = 0, mesh=None
              ) -> Tuple[np.ndarray, dict]:
     """Returns (scores [n_vertices], stats).  Unreached vertices keep the
     teleport mass only.
@@ -90,13 +78,12 @@ def pagerank(edges: np.ndarray, n_vertices: int, m: int,
     (``repro.graph.engine``) — all ``iters`` rounds of SpMV + planned
     reduce fused into ONE jitted dispatch on a mesh of ``m`` devices
     (``mesh`` or the process defaults); float32, tolerance-bounded against
-    the sim oracle.  ``use_kernel`` selects the ELL Pallas SpMV on both
-    backends; ``stats["engine"]`` carries the dispatch/sync report.
+    the sim oracle.  ``stats["engine"]`` carries the dispatch/sync report.
     """
     parts = build_partitions(edges, n_vertices, m, seed=seed)
     if backend == "device":
         return _pagerank_device(parts, n_vertices, degrees, iters, damping,
-                                use_kernel, seed, fabric, mesh)
+                                seed, fabric, mesh)
     ar = SparseAllreduce(m, degrees, backend=backend, fabric=fabric,
                          seed=seed)
     cstats = ar.config([p.out_idx.astype(np.uint32) for p in parts],
@@ -110,8 +97,7 @@ def pagerank(edges: np.ndarray, n_vertices: int, m: int,
     reduce_time = 0.0
     for it in range(iters):
         for i, p in enumerate(parts):
-            q_partial[i] = p.spmv_ell(p_in[i], use_kernel) if use_kernel \
-                else p.spmv(p_in[i])
+            q_partial[i] = p.spmv(p_in[i])
         in_raw = ar.reduce(q_partial)
         if ar.stats is not None:
             reduce_time += ar.stats.reduce_time_s
@@ -128,7 +114,7 @@ def pagerank(edges: np.ndarray, n_vertices: int, m: int,
 
 
 def make_pagerank_app(parts: List[Partition], n_vertices: int,
-                      damping: float = 0.85, use_kernel: bool = False):
+                      damping: float = 0.85):
     """The engine-agnostic PageRank pieces: ``(app, out_sets, in_sets)``.
 
     Shared by :func:`make_pagerank_engine` and the supervised loop
@@ -137,8 +123,7 @@ def make_pagerank_app(parts: List[Partition], n_vertices: int,
     from . import engine as eng
     app = eng.EngineApp(
         name="pagerank",
-        out_fn=lambda s, e: eng.ell_matvec(e["cols"], e["wts"], s,
-                                           use_kernel=use_kernel),
+        out_fn=lambda s, e: eng.ell_matvec(e["cols"], e["wts"], s),
         update_fn=lambda s, in_raw, e, ax:
             (1.0 - damping) / n_vertices + damping * in_raw)
     return (app,
@@ -160,15 +145,14 @@ def pagerank_state(parts: List[Partition], n_vertices: int,
 
 def make_pagerank_engine(parts: List[Partition], n_vertices: int,
                          degrees=(4, 2), damping: float = 0.85,
-                         use_kernel: bool = False, seed: int = 0,
+                         seed: int = 0,
                          fabric: Fabric = EC2_2013, mesh=None):
     """Build the device-resident PageRank engine (config once, reuse per
     ``run``): returns ``(engine, extras, p0)`` — everything
     ``engine.run(k, p0, extras)`` needs.  Shared by
     ``pagerank(backend="device")`` and the fig8/fig9 benchmarks."""
     from . import engine as eng
-    app, out_sets, in_sets = make_pagerank_app(parts, n_vertices, damping,
-                                               use_kernel)
+    app, out_sets, in_sets = make_pagerank_app(parts, n_vertices, damping)
     engine = eng.GraphEngine(out_sets, in_sets, app, degrees=degrees,
                              mesh=mesh, seed=seed, fabric=fabric)
     extras, p0 = pagerank_state(parts, n_vertices, engine.u_cap,
@@ -189,12 +173,12 @@ def assemble_pagerank_scores(parts: List[Partition], last_q: np.ndarray,
 
 
 def _pagerank_device(parts: List[Partition], n_vertices: int, degrees,
-                     iters: int, damping: float, use_kernel: bool,
+                     iters: int, damping: float,
                      seed: int, fabric: Fabric, mesh
                      ) -> Tuple[np.ndarray, dict]:
     """Device path: k PageRank rounds in one dispatch (graph engine)."""
     engine, extras, p0 = make_pagerank_engine(
-        parts, n_vertices, degrees, damping, use_kernel, seed, fabric, mesh)
+        parts, n_vertices, degrees, damping, seed, fabric, mesh)
     _, last_q, _ = engine.run(iters, p0, extras)
     scores = assemble_pagerank_scores(parts, last_q, n_vertices, damping)
     stats = {"config": engine.config_stats, "reduce_time_s": 0.0,

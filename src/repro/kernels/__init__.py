@@ -1,6 +1,6 @@
 """Pallas TPU kernels for the stack's compute hot-spots.
 
-``onehot_scatter`` / ``rank_merge`` / ``spmv_ell`` are the custom
-kernels (with ``ref.py`` pure-jnp references and ``ops.py`` dispatch
-wrappers); ``costmodel.py`` prices them for the autotuner.
+``onehot_scatter`` / ``rank_merge`` are the custom kernels (with
+``ref.py`` pure-jnp references and ``ops.py`` dispatch wrappers);
+``costmodel.py`` prices them for the autotuner.
 """

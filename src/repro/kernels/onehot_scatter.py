@@ -34,8 +34,14 @@ from jax.experimental import pallas as pl
 from repro.compat import CompilerParams, PrefetchScalarGridSpec
 
 # default tile shapes (out-rows, width, in-rows) — shared with the
-# costmodel so the instrumented tile/FLOP reports describe these kernels
-BM, BN, BK = 128, 128, 512
+# costmodel so the instrumented tile/FLOP reports describe these kernels.
+# BK is a multiple of 1024: the TPU lays a 1-D int32/f32 array out in
+# tiles of 1024, and Mosaic refuses a 1-D block that splits such a tile.
+BM, BN, BK = 128, 128, 1024
+
+# The one-hot matmul must reproduce f32 sums exactly: default TPU matmul
+# precision would round the values to bf16 on the MXU.
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _kernel(pos_ref, val_ref, out_ref, *, bm: int, bk: int):
@@ -52,7 +58,7 @@ def _kernel(pos_ref, val_ref, out_ref, *, bm: int, bk: int):
     out_ref[...] += jax.lax.dot_general(
         onehot, val_ref[...].astype(jnp.float32),
         (((0,), (0,)), ((), ())),                        # contract over bk
-        preferred_element_type=jnp.float32)
+        precision=_EXACT, preferred_element_type=jnp.float32)
 
 
 def _scaled_kernel(pos_ref, scale_ref, val_ref, out_ref, *, bm: int, bk: int):
@@ -72,15 +78,14 @@ def _scaled_kernel(pos_ref, scale_ref, val_ref, out_ref, *, bm: int, bk: int):
     v = val_ref[...].astype(jnp.float32) * scale_ref[...][:, None]
     out_ref[...] += jax.lax.dot_general(
         onehot, v, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_EXACT, preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("num_rows", "bm", "bn", "bk", "interpret"))
 def onehot_scatter_add(pos: jax.Array, val: jax.Array, num_rows: int,
-                       *, scale: jax.Array | None = None,
-                       bm: int = BM, bn: int = BN, bk: int = BK,
-                       interpret: bool = True) -> jax.Array:
+                       *, interpret: bool, scale: jax.Array | None = None,
+                       bm: int = BM, bn: int = BN, bk: int = BK) -> jax.Array:
     """out[num_rows, W] = scatter-add of val [C, W] at rows pos [C].
 
     Out-of-range pos (e.g. drop bins, padding parked at num_rows) fall off
@@ -89,6 +94,9 @@ def onehot_scatter_add(pos: jax.Array, val: jax.Array, num_rows: int,
     ``scale`` [C] f32, when given, multiplies each source row in-register
     before the one-hot matmul — the fused dequantization hook for the
     int8 wire format (``val`` stays in its on-wire dtype end to end).
+
+    ``interpret`` is the caller's decision (``kernels.ops`` passes True
+    only off-TPU): the Pallas interpreter is never a silent default.
     """
     c, w = val.shape
     # pad to tile multiples
@@ -150,7 +158,7 @@ def _banded_kernel(starts_ref, pos_ref, val_ref, out_ref, *, bm: int, bk: int):
     out_ref[...] += jax.lax.dot_general(
         onehot, val_ref[...].astype(jnp.float32),
         (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_EXACT, preferred_element_type=jnp.float32)
 
 
 def _banded_scaled_kernel(starts_ref, pos_ref, scale_ref, val_ref, out_ref,
@@ -169,16 +177,16 @@ def _banded_scaled_kernel(starts_ref, pos_ref, scale_ref, val_ref, out_ref,
     v = val_ref[...].astype(jnp.float32) * scale_ref[...][:, None]
     out_ref[...] += jax.lax.dot_general(
         onehot, v, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=_EXACT, preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("num_rows", "band", "bm", "bn",
                                              "bk", "interpret"))
 def banded_onehot_scatter_add(pos: jax.Array, val: jax.Array, num_rows: int,
-                              *, band: int, scale: jax.Array | None = None,
+                              *, band: int, interpret: bool,
+                              scale: jax.Array | None = None,
                               bm: int = BM, bn: int = BN,
-                              bk: int = BK, interpret: bool = True
-                              ) -> jax.Array:
+                              bk: int = BK) -> jax.Array:
     """Band-limited scatter-add: requires ``pos`` non-decreasing with at most
     ``band`` sources per destination row (rows parked at >= num_rows — drop
     bins / padding — must sit at the tail).

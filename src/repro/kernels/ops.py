@@ -2,8 +2,9 @@
 
 ``segment_compact`` / ``merge_add`` here are drop-in, kernel-backed versions
 of the pure-jnp ones in ``core.sparse_vec`` (which remain the oracles).
-``INTERPRET`` switches Pallas to interpret mode off-TPU; on TPU hardware the
-same BlockSpecs compile natively.
+Each call decides interpret mode from the backend it is traced for
+(:func:`interpret_mode`): the Pallas interpreter off-TPU, the compiled
+kernel on the chip.
 
 Merge modes (``mode="fused" | "banded"``): both run the same rank-merge +
 compact + one-hot scatter-add pipeline; ``banded`` additionally exploits
@@ -23,11 +24,14 @@ import jax.numpy as jnp
 from repro.core.sparse_vec import SENTINEL, SparseChunk
 from .onehot_scatter import banded_onehot_scatter_add, onehot_scatter_add
 from .rank_merge import rank_counts
-from .spmv_ell import spmv_ell
-
-INTERPRET = jax.default_backend() != "tpu"
 
 MERGE_KERNEL_MODES = ("fused", "banded")
+
+
+def interpret_mode() -> bool:
+    """True off-TPU (Pallas interpreter), False on the chip.  Asked per
+    call, at trace time — never once at import."""
+    return jax.default_backend() != "tpu"
 
 
 def _check_mode(mode: str) -> None:
@@ -88,11 +92,11 @@ def _compact_scatter_add(merged_idx: jax.Array, ranks: Optional[jax.Array],
                 scale = jnp.zeros_like(scale).at[ranks].set(scale)
         out_val = banded_onehot_scatter_add(
             pos, v2, out_capacity, band=band, scale=scale,
-            interpret=INTERPRET).astype(out_dtype)
+            interpret=interpret_mode()).astype(out_dtype)
     else:
         final_pos = pos if ranks is None else pos[ranks]
         out_val = onehot_scatter_add(final_pos, v2, out_capacity, scale=scale,
-                                     interpret=INTERPRET).astype(out_dtype)
+                                     interpret=interpret_mode()).astype(out_dtype)
     if val.ndim == 1:
         out_val = out_val[:, 0]
     return (SparseChunk(idx=out_idx, val=out_val),
@@ -132,9 +136,9 @@ def merge_add(a: SparseChunk, b: SparseChunk,
     ca, cb = a.capacity, b.capacity
     out_capacity = out_capacity or (ca + cb)
     rank_a = jnp.arange(ca, dtype=jnp.int32) + rank_counts(
-        a.idx, b.idx, strict=True, interpret=INTERPRET, banded=banded)
+        a.idx, b.idx, strict=True, interpret=interpret_mode(), banded=banded)
     rank_b = jnp.arange(cb, dtype=jnp.int32) + rank_counts(
-        b.idx, a.idx, strict=False, interpret=INTERPRET, banded=banded)
+        b.idx, a.idx, strict=False, interpret=interpret_mode(), banded=banded)
     merged_idx = jnp.zeros((ca + cb,), jnp.uint32)
     merged_idx = merged_idx.at[rank_a].set(a.idx)
     merged_idx = merged_idx.at[rank_b].set(b.idx)
@@ -199,7 +203,7 @@ def merge_sorted_runs(idx: jax.Array, val: jax.Array, out_capacity: int,
             if s == r:
                 continue
             rk = rk + rank_counts(idx[r], idx[s], strict=(s > r),
-                                  interpret=INTERPRET, banded=banded)
+                                  interpret=interpret_mode(), banded=banded)
         ranks.append(rk)
     rank = jnp.stack(ranks).reshape((total,))        # bijection on [0, total)
     flat_idx = idx.reshape((total,))
@@ -212,7 +216,3 @@ def merge_sorted_runs(idx: jax.Array, val: jax.Array, out_capacity: int,
         mode=mode, band=k, scale=scale, out_dtype=out_dtype)
     return out, jnp.maximum(n_unique - out_capacity, 0)
 
-
-def spmv(cols: jax.Array, weights: jax.Array, x: jax.Array) -> jax.Array:
-    """ELL SpMV (PageRank hotspot)."""
-    return spmv_ell(cols, weights, x, interpret=INTERPRET)

@@ -31,8 +31,10 @@ from repro.compat import CompilerParams, PrefetchScalarGridSpec
 
 _BIAS = -(2 ** 31)
 
-# default compare-plane tile shape — shared with the costmodel
-BM, BN = 512, 512
+# default compare-plane tile shape — shared with the costmodel.  1-D
+# blocks are whole multiples of the TPU's 1024-element tile of a 1-D
+# uint32 array (Mosaic refuses a block that splits one).
+BM, BN = 1024, 1024
 
 
 def _kernel(a_ref, b_ref, cnt_ref, *, strict: bool):
@@ -139,10 +141,13 @@ def _banded_kernel(ae_ref, be_ref, a_ref, b_ref, cnt_ref, *, strict: bool,
 
 @functools.partial(jax.jit, static_argnames=("strict", "bm", "bn",
                                              "interpret", "banded"))
-def rank_counts(a: jax.Array, b: jax.Array, *, strict: bool = True,
-                bm: int = BM, bn: int = BN,
-                interpret: bool = True, banded: bool = False) -> jax.Array:
-    """counts[i] = #{j : b_j < a_i} (strict) or <= (not strict); uint32 in."""
+def rank_counts(a: jax.Array, b: jax.Array, *, interpret: bool,
+                strict: bool = True, bm: int = BM, bn: int = BN,
+                banded: bool = False) -> jax.Array:
+    """counts[i] = #{j : b_j < a_i} (strict) or <= (not strict); uint32 in.
+
+    ``interpret`` is the caller's decision (``kernels.ops`` passes True
+    only off-TPU): the Pallas interpreter is never a silent default."""
     ca, cb = a.shape[0], b.shape[0]
     # pad a with MAX (counts for pads are garbage, sliced off), b with MAX
     # (never counted by '<' against real values; '<=' against MAX pads of a

@@ -28,12 +28,3 @@ def rank_counts_ref(a: jnp.ndarray, b: jnp.ndarray, side: str) -> jnp.ndarray:
         return jnp.searchsorted(bi, ai, side="left").astype(jnp.int32)
     return jnp.searchsorted(bi, ai, side="right").astype(jnp.int32)
 
-
-def spmv_ell_ref(cols: jnp.ndarray, weights: jnp.ndarray,
-                 x: jnp.ndarray) -> jnp.ndarray:
-    """ELL SpMV: y[r] = sum_k weights[r, k] * x[cols[r, k]].
-
-    cols: int32 [R, K] (negative = padding), weights [R, K], x [N]."""
-    safe = jnp.maximum(cols, 0)
-    g = x[safe] * (cols >= 0)
-    return jnp.sum(weights * g, axis=1)

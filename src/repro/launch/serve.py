@@ -27,8 +27,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS, get_config
+from repro.launch.cache import use_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
-from repro.train.step import (make_decode_greedy_step,
+from repro.train.step import (init_params_sharded, make_decode_greedy_step,
                               make_prefill_greedy_step, mesh_ctx)
 
 
@@ -112,15 +114,16 @@ def main(argv=None):
     ap.add_argument("--head-size", type=int, default=64,
                     help="Zipf hot-set size for the frozen dispatch plan")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
     ndev = len(jax.devices())
-    mesh = jax.make_mesh((ndev // args.model_axis, args.model_axis),
-                         ("data", "model"))
+    mesh = make_mesh((ndev // args.model_axis, args.model_axis),
+                     ("data", "model"))
     mc = mesh_ctx(mesh)
-    params = T.init_params(cfg, mc.tp, seed=args.seed)
+    params = init_params_sharded(cfg, mesh, seed=args.seed)
 
     if cfg.enc_layers or cfg.img_tokens:
         gen = _fixed_batch_generate(cfg, mesh, params, args)

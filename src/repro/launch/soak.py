@@ -122,6 +122,7 @@ def run_train(args) -> int:
     from jax.tree_util import tree_map
 
     from repro.configs import get_config
+    from repro.launch.mesh import make_mesh
     from repro.launch.train import batch_stream
     from repro.models import transformer as T
     from repro.optim.adamw import AdamW, AdamWState
@@ -152,9 +153,8 @@ def run_train(args) -> int:
         key = (tuple(assign), frozenset(dead_roles), r_now)
         hit = step_cache.get(key)
         if hit is None:
-            mesh = jax.sharding.Mesh(
-                np.array([pool[p] for p in assign]).reshape(len(assign), 1),
-                ("data", "model"))
+            mesh = make_mesh((len(assign), 1), ("data", "model"),
+                             devices=[pool[p] for p in assign])
             fn, _ = make_train_step(
                 cfg, mesh, sync=args.sync, opt=AdamW(lr=args.lr),
                 dp_degrees=None, sync_merge=args.merge,

@@ -22,17 +22,19 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import List
 
 import jax
-import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.store import save as ckpt_save
 from repro.configs import ARCHS, get_config
 from repro.data.pipeline import Batcher
-from repro.models import transformer as T
-from repro.optim.adamw import AdamW
-from repro.train.step import make_train_step, mesh_ctx
+from repro.launch.cache import use_compile_cache
+from repro.launch.mesh import make_mesh
+from repro.optim.adamw import AdamW, AdamWState
+from repro.train.step import _ns, init_params_sharded, make_train_step
 
 
 def batch_stream(cfg, batch: int, seq: int, seed: int = 0):
@@ -59,7 +61,23 @@ def batch_stream(cfg, batch: int, seq: int, seed: int = 0):
         yield b
 
 
-def main(argv=None):
+def init_sharded_state(cfg, mesh, seed: int = 0):
+    """Parameters and AdamW state created in place on the mesh, sharded as
+    the train step expects them (nothing is staged on device 0 first)."""
+    params = init_params_sharded(cfg, mesh, seed=seed)
+    shard = jax.tree.map(lambda x: x.sharding, params)
+    opt_shard = AdamWState(step=NamedSharding(mesh, P()), m=shard, v=shard)
+    opt_state = jax.jit(AdamW().init, out_shardings=opt_shard)(params)
+    return params, opt_state
+
+
+def main(argv=None) -> float:
+    """Run the launcher; returns the final step's loss."""
+    return train(argv)[-1]
+
+
+def train(argv=None) -> List[float]:
+    """Run the launcher; returns every step's loss."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b", choices=list(ARCHS))
     ap.add_argument("--reduced", action="store_true",
@@ -130,6 +148,7 @@ def main(argv=None):
     ap.add_argument("--ckpt", default="")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -140,8 +159,7 @@ def main(argv=None):
 
     ndev = len(jax.devices())
     dsize = args.data_axis or (ndev // args.model_axis)
-    mesh = jax.make_mesh((dsize, args.model_axis), ("data", "model"))
-    mc = mesh_ctx(mesh)
+    mesh = make_mesh((dsize, args.model_axis), ("data", "model"))
     dead = {int(x) for x in args.dead.split(",") if x} or None
     repl = ""
     if args.replication > 1 or dead:
@@ -157,31 +175,36 @@ def main(argv=None):
     else:
         degs = tuple(int(x) for x in args.dp_degrees.split(","))
         dp_degrees = {"data": degs}
-    step, _ = make_train_step(cfg, mesh, sync=args.sync,
-                              opt=AdamW(lr=args.lr),
-                              microbatch=args.microbatch,
-                              dp_degrees=dp_degrees,
-                              sparse_tokens_hint=max(
-                                  8, args.batch * args.seq // dsize),
-                              sync_merge=args.merge, sync_wire=args.wire,
-                              replication=args.replication, dead=dead,
-                              retune=args.retune,
-                              sync_overlap=args.sync_overlap,
-                              sync_bucket_bytes=args.sync_bucket_kb * 1024)
-    params = T.init_params(cfg, mc.tp, seed=args.seed)
-    opt_state = AdamW().init(params)
+    step, specs = make_train_step(cfg, mesh, sync=args.sync,
+                                opt=AdamW(lr=args.lr),
+                                microbatch=args.microbatch,
+                                dp_degrees=dp_degrees,
+                                sparse_tokens_hint=max(
+                                    8, args.batch * args.seq // dsize),
+                                sync_merge=args.merge, sync_wire=args.wire,
+                                replication=args.replication, dead=dead,
+                                retune=args.retune,
+                                sync_overlap=args.sync_overlap,
+                                sync_bucket_bytes=args.sync_bucket_kb * 1024)
+    params, opt_state = init_sharded_state(cfg, mesh, seed=args.seed)
+    batch_shard = _ns(mesh, specs["batch"])
     stream = batch_stream(cfg, args.batch, args.seq, seed=args.seed)
 
     t_start = time.time()
     r = args.replication
+    losses = []
     for i in range(args.steps):
-        batch = {k: jnp.asarray(v) for k, v in next(stream).items()}
+        batch = next(stream)
         if r > 1:
             # mirror the logical batch onto every replica slab: device
             # i + j*(data/r) sees logical shard i's rows for all j
-            batch = {k: jnp.tile(v, (r,) + (1,) * (v.ndim - 1))
+            batch = {k: np.tile(v, (r,) + (1,) * (v.ndim - 1))
                      for k, v in batch.items()}
+        # host -> each device's own rows, no detour through device 0
+        batch = {k: jax.device_put(v, batch_shard[k])
+                 for k, v in batch.items()}
         params, opt_state, m = step(params, opt_state, batch)
+        losses.append(m["loss"])
         if i % 10 == 0 or i == args.steps - 1:
             dt = time.time() - t_start
             tput = (i + 1) * args.batch * args.seq / dt
@@ -192,7 +215,7 @@ def main(argv=None):
         ckpt_save(args.ckpt, {"params": params},
                   meta={"arch": cfg.name, "steps": args.steps})
         print(f"checkpoint -> {args.ckpt}")
-    return float(m["loss"])
+    return [float(x) for x in losses]
 
 
 if __name__ == "__main__":

@@ -80,9 +80,9 @@ class SupervisedEngineLoop:
             plan_cache=plan_cache)
 
     def _mesh(self):
-        import jax
-        return jax.sharding.Mesh(
-            np.array([self.pool[p] for p in self.assignment]), ("nodes",))
+        from repro.launch.mesh import make_mesh
+        return make_mesh((len(self.assignment),), ("nodes",),
+                         devices=[self.pool[p] for p in self.assignment])
 
     # ------------------------------------------------------------------
     def _supervise(self, rnd: int) -> None:

@@ -42,6 +42,7 @@ from repro.core.api import SparseAllreduce
 from repro.core.faults import FailureSchedule
 from repro.core.netmodel import EC2_2013, Fabric
 from repro.core.replication import DeadLogicalNode
+from repro.launch.mesh import make_mesh
 from .events import (GROUP_LOST, NO_FAULT, QUORUM_LOST, REPLICA_ABSORBED,
                      FaultEvent, QuorumLost, classify)
 
@@ -169,7 +170,7 @@ class ResilientAllreduce:
             if len(devs) < m_phys:
                 raise ValueError(
                     f"{len(devs)} devices < {m_phys} physical nodes")
-            mesh = jax.sharding.Mesh(np.array(devs[:m_phys]), ("nodes",))
+            mesh = make_mesh((m_phys,), ("nodes",), devices=devs)
         self.mesh = mesh
         # The base instance is always fault-free at config time; dead sets
         # are applied per-reduce via reconfig_dead (incremental repair).
@@ -235,7 +236,6 @@ class ResilientAllreduce:
     def _shrink_for(self, ev: FaultEvent) -> Tuple[SparseAllreduce,
                                                    Tuple[int, ...]]:
         """The survivor instance for ``ev`` (cached per survivor set)."""
-        import jax
         survivors = ev.survivors
         m2 = len(survivors)
         alive = [i for i in range(self.num_physical) if i not in ev.dead]
@@ -252,8 +252,8 @@ class ResilientAllreduce:
             return hit[0], survivors
         degrees, source = self._survivor_degrees(m2, r2)
         pool = list(self.mesh.devices.flat)
-        mesh2 = jax.sharding.Mesh(
-            np.array([pool[i] for i in alive[: m2 * r2]]), ("nodes",))
+        mesh2 = make_mesh((m2 * r2,), ("nodes",),
+                          devices=[pool[i] for i in alive[: m2 * r2]])
         ar2 = SparseAllreduce(
             m2, degrees, backend="device", replication=r2, dead=None,
             fabric=self.fabric, seed=self.seed, value_width=self.base.width,

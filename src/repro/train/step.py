@@ -809,6 +809,16 @@ def _ns(mesh: Mesh, spec_tree):
                         is_leaf=lambda x: isinstance(x, P))
 
 
+def init_params_sharded(cfg: ModelConfig, mesh: Mesh, seed: int = 0):
+    """``transformer.init_params`` created in place on ``mesh``: the init
+    runs under ``jit`` with the steps' parameter shardings, so each device
+    computes its own shard and nothing is staged on device 0 first."""
+    mc = mesh_ctx(mesh)
+    pspec = full_model_pspec(cfg, mc.tp, mc.dp_axes)
+    return jax.jit(lambda: T.init_params(cfg, mc.tp, seed=seed),
+                   out_shardings=_ns(mesh, pspec))()
+
+
 # ---------------------------------------------------------------------------
 # Serve steps
 # ---------------------------------------------------------------------------

@@ -208,6 +208,7 @@ def test_ruff_config_matches_if_available():
 # ---------------------------------------------------------------------------
 
 AUDIT_PRELUDE = r"""
+from repro.launch.mesh import make_mesh
 import numpy as np, jax, jax.numpy as jnp
 from repro.core.api import SparseAllreduce
 from repro.analysis.auditor import (audit_callable, audit_engine,
@@ -222,7 +223,7 @@ def configured(degs, r, seed=None):
     in_idx = [rng.choice(4096, rng.randint(5, 16), replace=False)
               .astype(np.uint32) for _ in range(m)]
     ar = SparseAllreduce(m, degs, backend="device", replication=r,
-                         mesh=jax.make_mesh((m * r,), ("d",)), seed=m)
+                         mesh=make_mesh((m * r,), ("d",)), seed=m)
     ar.config(out_idx, in_idx)
     return ar
 """
@@ -290,6 +291,7 @@ def test_audit_catches_injected_extra_collective_and_callback():
 
 
 ENGINE_AUDIT_CODE = r"""
+from repro.launch.mesh import make_mesh
 import numpy as np, jax
 from repro.data.pipeline import powerlaw_graph
 from repro.graph.pagerank import build_partitions, make_pagerank_engine
@@ -299,7 +301,7 @@ from repro.analysis.auditor import audit_engine, collective_counts, \
 edges = powerlaw_graph(300, 1200, seed=1)
 parts = build_partitions(edges, 300, 8)
 engine, extras, p0 = make_pagerank_engine(
-    parts, 300, degrees=(4, 2), mesh=jax.make_mesh((8,), ("d",)))
+    parts, 300, degrees=(4, 2), mesh=make_mesh((8,), ("d",)))
 
 for k in (1, 7):
     rep = audit_engine(engine, k, p0, extras)
@@ -340,6 +342,7 @@ def test_audit_engine_one_dispatch_per_run():
 
 
 TRAIN_AUDIT_CODE = r"""
+from repro.launch.mesh import make_mesh
 import numpy as np, jax, jax.numpy as jnp
 from repro.configs import ARCHS, get_config
 from repro.models import transformer as T
@@ -348,7 +351,7 @@ from repro.train.step import make_train_step
 from repro.analysis.auditor import audit_callable
 
 cfg = get_config(sorted(ARCHS)[0]).reduced()
-mesh = jax.make_mesh((4, 1), ("data", "model"))
+mesh = make_mesh((4, 1), ("data", "model"))
 step, _ = make_train_step(cfg, mesh, sync="sparse", donate=False)
 params = T.init_params(cfg, tp=1, seed=0)
 opt = AdamW().init(params)

@@ -19,6 +19,7 @@ from repro.kernels.onehot_scatter import (band_inner_tiles,
                                           onehot_scatter_add)
 from repro.kernels.rank_merge import rank_counts, rank_tile_stats
 from repro.kernels.ref import rank_counts_ref
+from repro.launch.mesh import make_mesh
 
 
 def _powerlaw_runs(k, cap, width, seed):
@@ -169,10 +170,9 @@ def test_mode_validation():
         SparseAllreduce(8, (4, 2), merge="bandit")
     from repro.train.step import make_train_step
     from repro.configs import get_config
-    import jax
     with pytest.raises(ValueError):
         make_train_step(get_config("qwen1.5-0.5b").reduced(),
-                        jax.make_mesh((1, 1), ("data", "model")),
+                        make_mesh((1, 1), ("data", "model")),
                         sync_merge="bogus")
 
 
@@ -190,7 +190,7 @@ def test_banded_rank_counts_parity(strict, bm, bn):
         b = np.sort(rng.randint(0, 5000, cb).astype(np.uint32))
         a[-max(1, ca // 5):] = 0xFFFFFFFF      # sentinel tails
         got = rank_counts(jnp.asarray(a), jnp.asarray(b), strict=strict,
-                          bm=bm, bn=bn, banded=True)
+                          bm=bm, bn=bn, banded=True, interpret=True)
         ref = rank_counts_ref(jnp.asarray(a), jnp.asarray(b),
                               "left" if strict else "right")
         np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
@@ -199,7 +199,7 @@ def test_banded_rank_counts_parity(strict, bm, bn):
 def test_banded_rank_counts_all_equal_streams():
     a = jnp.asarray(np.full(64, 9, np.uint32))
     for strict in (True, False):
-        got = rank_counts(a, a, strict=strict, bm=16, bn=16, banded=True)
+        got = rank_counts(a, a, strict=strict, bm=16, bn=16, banded=True, interpret=True)
         want = np.full(64, 0 if strict else 64, np.int32)
         np.testing.assert_array_equal(np.asarray(got), want)
 
@@ -237,8 +237,8 @@ def test_banded_scatter_kernel_parity_monotone_pos():
     val = rng.randn(c, 5).astype(np.float32)
     pos = jnp.asarray(pos_np.astype(np.int32))
     got = banded_onehot_scatter_add(pos, jnp.asarray(val), rows, band=band,
-                                    bm=64, bk=128)
-    ref = onehot_scatter_add(pos, jnp.asarray(val), rows, bm=64, bk=128)
+                                    bm=64, bk=128, interpret=True)
+    ref = onehot_scatter_add(pos, jnp.asarray(val), rows, bm=64, bk=128, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 
@@ -251,9 +251,9 @@ def test_banded_scatter_block_multiple_boundary():
     val = np.arange(len(pos_np), dtype=np.float32)[:, None]
     got = banded_onehot_scatter_add(jnp.asarray(pos_np.astype(np.int32)),
                                     jnp.asarray(val), 1024, band=band,
-                                    bk=bk)
+                                    bk=bk, interpret=True)
     ref = onehot_scatter_add(jnp.asarray(pos_np.astype(np.int32)),
-                             jnp.asarray(val), 1024, bk=bk)
+                             jnp.asarray(val), 1024, bk=bk, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
 
 

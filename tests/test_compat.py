@@ -1,134 +1,40 @@
-"""repro.compat: version-shim resolution (both branches).
+"""repro.compat: the re-exported JAX symbols are the installed ones and
+behave as the repo uses them.
 
-The resolvers are pure functions over module objects, so both the
-0.4.x branch and the promoted-API branch are testable on any installed
-JAX by handing them fakes.  The layering rule (these symbols resolve
-only in compat.py) lives in the lint engine now — rules RA101/RA102 in
-``repro.analysis.rules``, enforced repo-wide by tests/test_analysis.py.
+The layering rule (these symbols are imported only through compat.py)
+lives in the lint engine — rules RA101/RA102 in ``repro.analysis.rules``,
+enforced repo-wide by tests/test_analysis.py.
 """
-import types
+import inspect
 
+import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from repro import compat
+from repro.launch.mesh import make_mesh
 
 
-# ---------------------------------------------------------------------------
-# shard_map resolution
-# ---------------------------------------------------------------------------
-
-def test_resolve_shard_map_new_api():
-    marker = object()
-    fake_jax = types.SimpleNamespace(shard_map=marker)
-    fn, kwarg = compat.resolve_shard_map(fake_jax)
-    assert fn is marker
-    assert kwarg == "check_vma"
+def test_shard_map_is_installed_jax_shard_map():
+    assert compat.shard_map is jax.shard_map
+    assert "check_vma" in inspect.signature(compat.shard_map).parameters
 
 
-def test_resolve_shard_map_old_api():
-    marker = object()
-    fake_jax = types.SimpleNamespace()                  # no jax.shard_map
-    fake_exp = types.SimpleNamespace(shard_map=marker)
-    fn, kwarg = compat.resolve_shard_map(fake_jax, fake_exp)
-    assert fn is marker
-    assert kwarg == "check_rep"
-
-
-def test_resolve_shard_map_promoted_name_old_kwarg():
-    """Some releases promoted jax.shard_map before renaming check_rep to
-    check_vma — the kwarg must be detected from the signature, not from
-    where the symbol lives."""
-
-    def promoted(f, *, mesh, in_specs, out_specs, check_rep=True):
-        pass
-
-    fake_jax = types.SimpleNamespace(shard_map=promoted)
-    fn, kwarg = compat.resolve_shard_map(fake_jax)
-    assert fn is promoted
-    assert kwarg == "check_rep"
-
-
-def test_resolve_shard_map_new_signature():
-    def new_style(f, *, mesh, in_specs, out_specs, check_vma=True):
-        pass
-
-    fake_jax = types.SimpleNamespace(shard_map=new_style)
-    assert compat.resolve_shard_map(fake_jax)[1] == "check_vma"
-
-
-def test_resolve_shard_map_on_installed_jax():
-    import jax
-    fn, kwarg = compat.resolve_shard_map(jax)
-    assert callable(fn)
-    assert kwarg in ("check_vma", "check_rep")
-
-
-@pytest.mark.parametrize("kwarg", ["check_vma", "check_rep"])
-def test_make_shard_map_translates_check_kwarg(kwarg):
-    seen = {}
-
-    def raw(f, *, mesh, in_specs, out_specs, **kwargs):
-        seen.update(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    **kwargs)
-        return "ok"
-
-    wrapped = compat.make_shard_map(raw, kwarg)
-    out = wrapped(lambda: None, mesh="m", in_specs="i", out_specs="o",
-                  check_vma=False)
-    assert out == "ok"
-    assert seen[kwarg] is False                 # renamed (or passed through)
-    other = "check_rep" if kwarg == "check_vma" else "check_vma"
-    assert other not in seen
-
-
-def test_make_shard_map_omits_check_when_unset():
-    seen = {}
-
-    def raw(f, *, mesh, in_specs, out_specs, **kwargs):
-        seen.update(kwargs)
-
-    compat.make_shard_map(raw, "check_rep")(
-        lambda: None, mesh=1, in_specs=2, out_specs=3)
-    assert "check_rep" not in seen and "check_vma" not in seen
-
-
-# ---------------------------------------------------------------------------
-# Pallas compiler params resolution
-# ---------------------------------------------------------------------------
-
-def test_resolve_compiler_params_new_name():
-    class NewCP:
-        pass
-    fake = types.SimpleNamespace(CompilerParams=NewCP)
-    assert compat.resolve_compiler_params(fake) is NewCP
-
-
-def test_resolve_compiler_params_old_name():
-    class OldCP:
-        pass
-    fake = types.SimpleNamespace(TPUCompilerParams=OldCP)
-    assert compat.resolve_compiler_params(fake) is OldCP
+@pytest.mark.parametrize("check_vma", [True, False, None])
+def test_shard_map_runs_with_check_vma(check_vma):
+    mesh = make_mesh((1,), ("d",))
+    kw = {} if check_vma is None else {"check_vma": check_vma}
+    f = compat.shard_map(lambda x: x * 2.0, mesh=mesh, in_specs=P("d"),
+                         out_specs=P("d"), **kw)
+    np.testing.assert_array_equal(np.asarray(f(jnp.arange(4.0))),
+                                  np.arange(4.0) * 2.0)
 
 
 def test_compiler_params_usable_on_installed_jax():
     cp = compat.CompilerParams(dimension_semantics=("parallel",))
     assert cp.dimension_semantics == ("parallel",)
-
-
-# ---------------------------------------------------------------------------
-# Pallas scalar-prefetch grid spec resolution
-# ---------------------------------------------------------------------------
-
-def test_resolve_prefetch_grid_spec_historical_name():
-    class GS:
-        pass
-    fake = types.SimpleNamespace(PrefetchScalarGridSpec=GS)
-    assert compat.resolve_prefetch_grid_spec(fake) is GS
-
-
-def test_resolve_prefetch_grid_spec_missing_raises():
-    with pytest.raises(ImportError):
-        compat.resolve_prefetch_grid_spec(types.SimpleNamespace())
 
 
 def test_prefetch_grid_spec_usable_on_installed_jax():

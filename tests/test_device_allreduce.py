@@ -23,6 +23,7 @@ def _run(code: str):
 
 
 UNION_CODE = r"""
+from repro.launch.mesh import make_mesh
 import numpy as np, jax, jax.numpy as jnp
 from repro.core.allreduce import make_device_plan, run_union_allreduce
 from repro.core.sparse_vec import HashPerm
@@ -43,7 +44,7 @@ for n in range(M):
         acc[int(h[j])] = acc.get(int(h[j]), 0.0) + float(ov[j])
 want_idx = np.array(sorted(acc), np.uint32)
 want_val = np.array([acc[int(k)] for k in want_idx])
-mesh = jax.make_mesh((8,), ("d",))
+mesh = make_mesh((8,), ("d",))
 for degs in [(4, 2), (2, 2, 2), (8,), (2, 4)]:
     plan = make_device_plan([("d", 8)], {"d": degs}, in_capacity=C,
                             out_capacity=M * C)
@@ -60,6 +61,7 @@ print("UNION_OK")
 
 
 PLANNED_CODE = r"""
+from repro.launch.mesh import make_mesh
 import numpy as np, jax, jax.numpy as jnp
 from repro.core.allreduce import make_device_plan
 from repro.core.planned import plan_sparse_allreduce
@@ -74,7 +76,7 @@ out_idx = [rng.randint(0, R, rng.randint(30, 120)).astype(np.uint32)
 out_val = [rng.randn(len(o)).astype(np.float32) for o in out_idx]
 in_idx = [rng.choice(R, rng.randint(20, 90), replace=False).astype(np.uint32)
           for _ in range(M)]
-mesh = jax.make_mesh((8,), ("d",))
+mesh = make_mesh((8,), ("d",))
 oracle = dense_oracle(out_idx, out_val, in_idx, perm)
 for degs in [(4, 2), (8,)]:
     dplan = make_device_plan([("d", 8)], {"d": degs}, 128, 1024)
@@ -100,6 +102,7 @@ print("PLANNED_OK")
 
 
 DENSE_CODE = r"""
+from repro.launch.mesh import make_mesh
 import numpy as np, jax, jax.numpy as jnp
 from jax import lax
 from repro.compat import shard_map
@@ -108,7 +111,7 @@ from repro.core.allreduce import (dense_allreduce_binary,
                                   dense_allreduce_hierarchical,
                                   make_device_plan)
 
-mesh = jax.make_mesh((8,), ("d",))
+mesh = make_mesh((8,), ("d",))
 x = np.random.RandomState(0).randn(8, 64).astype(np.float32)
 want = x.sum(0)
 plan = make_device_plan([("d", 8)], {"d": (4, 2)}, 8, 8)
@@ -130,6 +133,7 @@ print("DENSE_OK")
 
 
 SYNC_MODES_CODE = r"""
+from repro.launch.mesh import make_mesh
 import dataclasses
 import numpy as np, jax, jax.numpy as jnp
 from repro.configs import get_config
@@ -139,7 +143,7 @@ from repro.train.step import make_train_step
 
 cfg = dataclasses.replace(get_config("qwen1.5-0.5b").reduced(),
                           tie_embeddings=False)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 params0 = T.init_params(cfg, tp=2, seed=0)
 rng = np.random.RandomState(0)
 B, S = 8, 32
@@ -162,6 +166,7 @@ print("SYNC_MODES_OK")
 
 
 MICROBATCH_CODE = r"""
+from repro.launch.mesh import make_mesh
 import numpy as np, jax, jax.numpy as jnp
 from repro.configs import get_config
 from repro.models import transformer as T
@@ -169,7 +174,7 @@ from repro.optim.adamw import AdamW
 from repro.train.step import make_train_step
 
 cfg = get_config("qwen1.5-0.5b").reduced()
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 params0 = T.init_params(cfg, tp=2, seed=0)
 rng = np.random.RandomState(0)
 B, S = 8, 32
@@ -216,6 +221,7 @@ def test_microbatch_accumulation_equivalent():
 
 
 SERVE2D_CODE = r"""
+from repro.launch.mesh import make_mesh
 import dataclasses
 import numpy as np, jax, jax.numpy as jnp
 from repro.configs import get_config
@@ -224,7 +230,7 @@ from repro.train.step import make_decode_step, make_prefill_step
 
 cfg = dataclasses.replace(get_config("command-r-plus-104b").reduced(),
                           fsdp=True)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 params = T.init_params(cfg, tp=2, seed=0)
 rng = np.random.RandomState(0)
 B, S, MAX = 4, 12, 16
@@ -279,6 +285,7 @@ def test_serve2d_matches_gather_decode():
 
 
 KERNEL_UNION_CODE = r"""
+from repro.launch.mesh import make_mesh
 import numpy as np, jax, jax.numpy as jnp
 from repro.core.allreduce import make_device_plan, run_union_allreduce
 from repro.core.sparse_vec import HashPerm
@@ -293,7 +300,7 @@ for n in range(M):
     oi = rng.choice(R, nn, replace=False).astype(np.uint32)
     h = perm.fwd_np(oi); o = np.argsort(h)
     idx[n, :nn] = h[o]; val[n, :nn] = rng.randn(nn)
-mesh = jax.make_mesh((8,), ("d",))
+mesh = make_mesh((8,), ("d",))
 plan = make_device_plan([("d", 8)], {"d": (4, 2)}, C, M * C)
 oi1, ov1, _ = run_union_allreduce(mesh, plan, jnp.asarray(idx),
                                   jnp.asarray(val), use_kernel=False)
@@ -313,6 +320,7 @@ def test_pallas_kernel_inside_union_allreduce():
 
 
 FUSED_UNION_CODE = r"""
+from repro.launch.mesh import make_mesh
 import numpy as np, jax, jax.numpy as jnp
 from repro.core.api import SparseAllreduce
 from repro.core.sparse_vec import HashPerm
@@ -333,7 +341,7 @@ for n in range(M):
         acc[int(h[j])] = acc.get(int(h[j]), 0.0) + float(ov[j])
 want_idx = np.array(sorted(acc), np.uint32)
 want_val = np.array([acc[int(k)] for k in want_idx])
-mesh = jax.make_mesh((8,), ("d",))
+mesh = make_mesh((8,), ("d",))
 outs = {}
 for merge in ("sort", "fused", "banded"):
     ar = SparseAllreduce(8, (4, 2), backend="device", mesh=mesh, merge=merge)

@@ -61,6 +61,9 @@ def _declared_flags() -> set:
                         os.path.join(dirpath, f), ROOT))
                     flags |= set(re.findall(
                         r"add_argument\(\s*[\"'](--[\w-]+)", text))
+    # the chip smoke run is an entry point at the repo root
+    flags |= set(re.findall(r"add_argument\(\s*[\"'](--[\w-]+)",
+                            _read("chip_smoke.py")))
     return flags
 
 

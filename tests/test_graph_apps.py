@@ -22,13 +22,6 @@ def test_pagerank_matches_dense(graph, degrees):
     assert stats["reduce_time_s"] > 0
 
 
-def test_pagerank_with_pallas_kernel(graph):
-    edges, n = graph
-    ref = pagerank_dense_reference(edges, n, iters=5)
-    got, _ = pagerank(edges, n, m=4, degrees=(4,), iters=5, use_kernel=True)
-    np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-9)
-
-
 def test_pagerank_scores_positive_mass_conserved(graph):
     """Positive scores; total mass equals the dense formulation's (dangling
     vertices leak teleport mass in the simple iteration — both sides match)."""

@@ -7,11 +7,10 @@ from hypothesis import given, settings, strategies as st
 from repro.core import sparse_vec as sv
 from repro.core.sparse_vec import SparseChunk
 from repro.kernels import ops
-from repro.kernels.onehot_scatter import onehot_scatter_add
+from repro.kernels.onehot_scatter import (banded_onehot_scatter_add,
+                                          onehot_scatter_add)
 from repro.kernels.rank_merge import rank_counts
-from repro.kernels.ref import (onehot_scatter_add_ref, rank_counts_ref,
-                               spmv_ell_ref)
-from repro.kernels.spmv_ell import spmv_ell
+from repro.kernels.ref import onehot_scatter_add_ref, rank_counts_ref
 
 
 # ---------------------------------------------------------------------------
@@ -25,7 +24,7 @@ def test_onehot_scatter_sweep(c, w, rows, dtype):
     rng = np.random.RandomState(c + w)
     pos = rng.randint(-1, rows + 2, c).astype(np.int32)   # incl. out-of-range
     val = rng.randn(c, w).astype(dtype)
-    got = onehot_scatter_add(jnp.asarray(pos), jnp.asarray(val), rows)
+    got = onehot_scatter_add(jnp.asarray(pos), jnp.asarray(val), rows, interpret=True)
     ref = onehot_scatter_add_ref(jnp.asarray(pos), jnp.asarray(val), rows)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-3 if dtype == np.float16 else 1e-5,
@@ -38,7 +37,7 @@ def test_onehot_scatter_blockspec_sweep(bm, bk, bn):
     pos = rng.randint(0, 40, 200).astype(np.int32)
     val = rng.randn(200, 20).astype(np.float32)
     got = onehot_scatter_add(jnp.asarray(pos), jnp.asarray(val), 40,
-                             bm=bm, bk=bk, bn=bn)
+                             bm=bm, bk=bk, bn=bn, interpret=True)
     ref = onehot_scatter_add_ref(jnp.asarray(pos), jnp.asarray(val), 40)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-5,
                                atol=1e-5)
@@ -55,7 +54,7 @@ def test_onehot_scatter_blockspec_sweep(bm, bk, bn):
 def test_rank_counts_property(a, b, strict):
     a = np.sort(np.array(a, np.uint32))
     b = np.sort(np.array(b, np.uint32))
-    got = rank_counts(jnp.asarray(a), jnp.asarray(b), strict=strict)
+    got = rank_counts(jnp.asarray(a), jnp.asarray(b), strict=strict, interpret=True)
     ref = rank_counts_ref(jnp.asarray(a), jnp.asarray(b),
                           "left" if strict else "right")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
@@ -64,10 +63,10 @@ def test_rank_counts_property(a, b, strict):
 def test_rank_counts_with_sentinels():
     a = np.array([5, 10, 0xFFFFFFFF, 0xFFFFFFFF], np.uint32)
     b = np.array([1, 10, 0xFFFFFFFF], np.uint32)
-    got_l = np.asarray(rank_counts(jnp.asarray(a), jnp.asarray(b), strict=True))
+    got_l = np.asarray(rank_counts(jnp.asarray(a), jnp.asarray(b), strict=True, interpret=True))
     np.testing.assert_array_equal(got_l, [1, 1, 2, 2])
     got_r = np.asarray(rank_counts(jnp.asarray(a), jnp.asarray(b),
-                                   strict=False))
+                                   strict=False, interpret=True))
     np.testing.assert_array_equal(got_r, [1, 2, 3, 3])
 
 
@@ -77,9 +76,9 @@ def test_merge_is_permutation():
         a = np.sort(rng.randint(0, 500, ca).astype(np.uint32))
         b = np.sort(rng.randint(0, 500, cb).astype(np.uint32))
         ra = np.arange(ca) + np.asarray(
-            rank_counts(jnp.asarray(a), jnp.asarray(b), strict=True))
+            rank_counts(jnp.asarray(a), jnp.asarray(b), strict=True, interpret=True))
         rb = np.arange(cb) + np.asarray(
-            rank_counts(jnp.asarray(b), jnp.asarray(a), strict=False))
+            rank_counts(jnp.asarray(b), jnp.asarray(a), strict=False, interpret=True))
         assert sorted(list(ra) + list(rb)) == list(range(ca + cb))
 
 
@@ -127,18 +126,22 @@ def test_merge_add_kernel_property(ca, cb, w, seed):
 
 
 # ---------------------------------------------------------------------------
-# spmv_ell
+# interpret mode is the caller's decision, never a kernel default
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("r,k,n", [(8, 1, 16), (500, 17, 300), (256, 64, 1000),
-                                   (1, 5, 10)])
-@pytest.mark.parametrize("bm", [32, 256])
-def test_spmv_sweep(r, k, n, bm):
-    rng = np.random.RandomState(r + k)
-    cols = rng.randint(-1, n, (r, k)).astype(np.int32)
-    w = rng.randn(r, k).astype(np.float32)
-    x = rng.randn(n).astype(np.float32)
-    got = spmv_ell(jnp.asarray(cols), jnp.asarray(w), jnp.asarray(x), bm=bm)
-    ref = spmv_ell_ref(jnp.asarray(cols), jnp.asarray(w), jnp.asarray(x))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-5,
-                               atol=1e-5)
+@pytest.mark.parametrize("call", [
+    lambda p, v, i: onehot_scatter_add(p, v, 8),
+    lambda p, v, i: banded_onehot_scatter_add(p, v, 8, band=2),
+    lambda p, v, i: rank_counts(i, i),
+])
+def test_raw_kernels_require_interpret(call):
+    pos = jnp.zeros((8,), jnp.int32)
+    val = jnp.ones((8, 1), jnp.float32)
+    idx = jnp.arange(8, dtype=jnp.uint32)
+    with pytest.raises(TypeError, match="interpret"):
+        call(pos, val, idx)
+
+
+def test_interpret_mode_follows_backend():
+    import jax
+    assert ops.interpret_mode() == (jax.default_backend() != "tpu")

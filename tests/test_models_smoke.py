@@ -13,6 +13,7 @@ from repro.models import transformer as T
 from repro.optim.adamw import AdamW
 from repro.train.step import (make_decode_step, make_prefill_step,
                               make_train_step, mesh_ctx)
+from repro.launch.mesh import make_mesh
 
 warnings.filterwarnings("ignore")
 B, S, MAX = 2, 32, 48
@@ -20,7 +21,7 @@ B, S, MAX = 2, 32, 48
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def _batch(cfg, rng, with_labels=True):

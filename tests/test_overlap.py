@@ -232,6 +232,7 @@ def test_plan_cache_key_overlap_compat():
 # ---------------------------------------------------------------------------
 
 AUDIT_SYNC_CODE = r"""
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp, numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
@@ -242,7 +243,7 @@ from repro.core.allreduce import (dense_allreduce_hierarchical,
                                   make_device_plan)
 
 plan = make_device_plan([("d", 8)], {"d": (4, 2)}, 8, 8)
-mesh = jax.make_mesh((8,), ("d",))
+mesh = make_mesh((8,), ("d",))
 sizes = (64, 32, 96)
 
 def mk(schedule):
@@ -290,6 +291,7 @@ print("AUDIT_SYNC_OK")
 
 
 AUDIT_ENGINE_CODE = r"""
+from repro.launch.mesh import make_mesh
 import jax, numpy as np
 from repro.analysis.auditor import audit_engine
 from repro.data.pipeline import powerlaw_graph
@@ -298,7 +300,7 @@ from repro.graph.pagerank import build_partitions, make_pagerank_engine
 
 edges = powerlaw_graph(300, 1200, seed=1)
 parts = build_partitions(edges, 300, 8)
-mesh = jax.make_mesh((8,), ("d",))
+mesh = make_mesh((8,), ("d",))
 engine, extras, p0 = make_pagerank_engine(parts, 300, degrees=(4, 2),
                                           mesh=mesh)
 ov = GraphEngine([np.asarray(o) for o in engine.out_sets],
@@ -352,6 +354,7 @@ def test_audit_engine_overlap_and_injection():
 
 _SYNC_PRELUDE = r"""
 import dataclasses, numpy as np, jax, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.models import transformer as T
 from repro.train.step import make_sync_fn
@@ -389,7 +392,7 @@ def check_pair(mesh, tp, seed, **kw):
 
 
 HIER_PARITY_CODE = _SYNC_PRELUDE + r"""
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 check_pair(mesh, 2, 7, sync="hier", dp_degrees={"data": (2, 2)})
 check_pair(mesh, 2, 11, sync="hier", dp_degrees={"data": (4,)},
            replication=2)
@@ -425,12 +428,13 @@ def test_sync_parity_hier_bucketed():
 # ---------------------------------------------------------------------------
 
 ENGINE_PARITY_CODE = r"""
+from repro.launch.mesh import make_mesh
 import jax, jax.numpy as jnp, numpy as np
 from repro.data.pipeline import powerlaw_graph
 from repro.graph.engine import EngineApp, GraphEngine
 from repro.graph.pagerank import build_partitions, make_pagerank_engine
 
-mesh = jax.make_mesh((8,), ("d",))
+mesh = make_mesh((8,), ("d",))
 
 def leaves(x):
     return [np.asarray(l) for l in jax.tree.leaves(x)]
@@ -519,7 +523,7 @@ def test_engine_overlap_parity():
 # ---------------------------------------------------------------------------
 
 SWEEP_HIER_16_CODE = _SYNC_PRELUDE + r"""
-mesh = jax.make_mesh((8, 2), ("data", "model"))
+mesh = make_mesh((8, 2), ("data", "model"))
 for degs in [(4, 2), (2, 2, 2), (8,)]:
     for r in (1, 2):
         check_pair(mesh, 2, 13 + r, sync="hier", dp_degrees={"data": degs},
@@ -529,14 +533,14 @@ print("SWEEP_HIER_16_OK")
 """
 
 SWEEP_SPARSE_SORT_CODE = _SYNC_PRELUDE + r"""
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 check_pair(mesh, 2, 17, sync="sparse", dp_degrees={"data": (2, 2)},
            sync_merge="sort", sync_wire="raw", sparse_tokens_hint=32)
 print("SWEEP_SPARSE_SORT_OK")
 """
 
 SWEEP_SPARSE_FUSED_CODE = _SYNC_PRELUDE + r"""
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 check_pair(mesh, 2, 19, sync="sparse", dp_degrees={"data": (4,)},
            sync_merge="fused", sync_wire="delta", replication=2,
            sparse_tokens_hint=32)
@@ -544,13 +548,14 @@ print("SWEEP_SPARSE_FUSED_OK")
 """
 
 SWEEP_SPARSE_BANDED_CODE = _SYNC_PRELUDE + r"""
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 check_pair(mesh, 2, 23, sync="sparse", dp_degrees={"data": (2, 2)},
            sync_merge="banded", sync_wire="delta", sparse_tokens_hint=32)
 print("SWEEP_SPARSE_BANDED_OK")
 """
 
 TRAIN_STEP_CODE = r"""
+from repro.launch.mesh import make_mesh
 import dataclasses, numpy as np, jax, jax.numpy as jnp
 from repro.configs import get_config
 from repro.models import transformer as T
@@ -561,7 +566,7 @@ cfg = dataclasses.replace(
     get_config("qwen1.5-0.5b").reduced(d_model=64, d_ff=128, vocab=256,
                                        n_heads=2, n_kv=1, head_dim=32),
     tie_embeddings=False)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 rng = np.random.RandomState(0)
 batch = {"tokens": jnp.asarray(rng.randint(0, cfg.vocab, (8, 32)), jnp.int32),
          "labels": jnp.asarray(rng.randint(0, cfg.vocab, (8, 32)), jnp.int32)}

@@ -13,6 +13,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import transformer as T
+from repro.launch.mesh import make_mesh
 from repro.train.step import make_decode_step, make_prefill_step
 
 warnings.filterwarnings("ignore")
@@ -22,7 +23,7 @@ S, MAX, B = 24, 32, 2
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.mark.parametrize("arch,tol", [
@@ -82,6 +83,7 @@ _ENV8 = dict(os.environ,
              PYTHONPATH=_SRC + os.pathsep + os.environ.get("PYTHONPATH", ""))
 
 _CACHE_SHAPE_CODE = r"""
+from repro.launch.mesh import make_mesh
 import sys
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
@@ -94,7 +96,7 @@ MA = int(sys.argv[1])
 MAX = 16
 for arch in ("qwen1.5-0.5b", "granite-moe-3b-a800m", "jamba-1.5-large-398b"):
     cfg = get_config(arch).reduced()
-    mesh = jax.make_mesh((8 // MA, MA), ("data", "model"))
+    mesh = make_mesh((8 // MA, MA), ("data", "model"))
     mc = mesh_ctx(mesh)
     b = mc.dp
     params = T.init_params(cfg, tp=MA, seed=0)

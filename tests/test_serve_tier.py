@@ -27,7 +27,6 @@ import subprocess
 import sys
 import warnings
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -37,6 +36,7 @@ from repro.models import transformer as T
 from repro.serve import (ContinuousBatchingScheduler, DecodeService,
                          zipf_request_stream)
 from repro.serve.service import run_sequential_oracle
+from repro.launch.mesh import make_mesh
 
 warnings.filterwarnings("ignore")
 
@@ -50,7 +50,7 @@ MAX_SEQ = 24
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.fixture(scope="module")
@@ -295,6 +295,7 @@ def test_greedy_masks_padded_vocab_columns(qwen, mesh):
 # ---------------------------------------------------------------------------
 
 _CODE16 = r"""
+from repro.launch.mesh import make_mesh
 import numpy as np, jax, jax.numpy as jnp
 from repro.configs import get_config
 from repro.models import transformer as T
@@ -304,7 +305,7 @@ from repro.serve.dispatch import SparseServeDispatch
 from repro.serve.service import run_sequential_oracle
 
 cfg = get_config("granite-moe-3b-a800m").reduced()
-mesh = jax.make_mesh((8, 2), ("data", "model"))
+mesh = make_mesh((8, 2), ("data", "model"))
 params = T.init_params(cfg, tp=2, seed=0)
 reqs = zipf_request_stream(10, cfg.vocab, prompt_lens=(4, 6),
                            max_new=(1, 5), arrival_rate=0.8, seed=4)

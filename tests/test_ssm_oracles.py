@@ -14,6 +14,7 @@ from repro.configs import get_config
 from repro.models import ssm as SSM
 from repro.models.ssm import (_chunked_selective_scan, mamba_params,
                               mlstm_params)
+from repro.launch.mesh import make_mesh
 
 
 def test_chunked_selective_scan_vs_naive():
@@ -58,7 +59,7 @@ def test_mamba_train_vs_stepwise_decode(monkeypatch):
     # wrapping psum axes with a single-device mesh
     from repro.compat import shard_map
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     x = jnp.asarray(rng.randn(B, T, cfg.d_model), jnp.float32)
 
     def full(p, x):
@@ -95,7 +96,7 @@ def test_mlstm_train_vs_stepwise_decode(monkeypatch):
 
     from repro.compat import shard_map
     from jax.sharding import PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     x = jnp.asarray(rng.randn(B, T, cfg.d_model) * 0.3, jnp.float32)
 
     def full(p, x):

@@ -5,19 +5,19 @@ import pytest
 
 from repro.core import SparseAllreduce
 from repro.core.simulator import dense_oracle
+from repro.launch.mesh import make_mesh
 
 
 def test_train_loss_decreases():
     """Deterministic memorization check: repeated batch, loss must drop
     sharply (fresh-stream convergence is exercised by the launcher test)."""
-    import jax
     import jax.numpy as jnp
     from repro.configs import get_config
     from repro.models import transformer as T
     from repro.optim.adamw import AdamW
     from repro.train.step import make_train_step
     cfg = get_config("qwen1.5-0.5b").reduced()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     step, _ = make_train_step(cfg, mesh, opt=AdamW(lr=1e-3))
     params = T.init_params(cfg, 1, seed=0)
     opt = AdamW().init(params)
