@@ -1,0 +1,9 @@
+"""Device time of the all-to-all ops per union allreduce call, in ms,
+averaged over the chips (device trace)."""
+
+
+def read(ctx):
+    t, f = ctx.trace, ctx.facts
+    if t is None or "calls" not in f or "alltoall" not in t["collective_s"]:
+        return None
+    return 1e3 * t["collective_s"]["alltoall"] / f["calls"]
