@@ -34,6 +34,7 @@ from jax import lax
 from .sparse_vec import (SENTINEL, SparseChunk, bucket_partition,
                          concat_sorted_groups, segment_compact, sort_chunk)
 from .topology import ButterflyPlan, check_wire
+from repro.obs import scope
 
 
 # ---------------------------------------------------------------------------
@@ -85,6 +86,20 @@ class DevicePlan:
     def num_logical(self) -> int:
         """Logical shard count (== num_nodes unless replicated)."""
         return self.logical.num_nodes // self.replication
+
+    @property
+    def slots_received(self) -> int:
+        """Rows one node receives from the other nodes in one union
+        allreduce, as the static capacities fix them: (k - 1) buckets of
+        ``bucket_capacity`` at each down stage, and (k - 1) gathered chunks
+        at each up stage, a chunk being the last stage's merged capacity
+        times the degrees of the up stages already gathered."""
+        down = sum((st.degree - 1) * st.bucket_capacity for st in self.stages)
+        up, chunk = 0, self.stages[-1].merged_capacity if self.stages else 0
+        for st in reversed(self.stages):
+            up += (st.degree - 1) * chunk
+            chunk *= st.degree
+        return down + up
 
     def replica_groups(self):
         """[[physical ids] per logical shard] (see core.replication)."""
@@ -261,101 +276,112 @@ def sparse_allreduce_union(chunk: SparseChunk, plan: DevicePlan,
     for l, st in enumerate(plan.stages):
         e = edges[l].reshape((-1,))[-(st.degree + 1):]
         groups = list(map(list, st.axis_index_groups))
-        buckets, ovf = bucket_partition(chunk, e, st.degree,
-                                        st.bucket_capacity)
-        overflow = overflow + ovf
-        scale = None
-        if wire == "raw":
-            send_idx, send_val = buckets.idx, buckets.val
-        else:
-            # Bucket d covers [e[d], e[d+1]); ship offsets from e[d].
-            send_idx = _wc.pack_indices(buckets.idx,
-                                        e[:st.degree].astype(jnp.uint32),
-                                        widths[l])
-            send_val = buckets.val
-            if wire == "delta+bf16":
-                send_val = send_val.astype(jnp.bfloat16)
-            elif wire == "delta+int8ef":
-                send_val, scale = _wc.quant8_rows(send_val)
-        r_idx = lax.all_to_all(send_idx, st.axis_name, split_axis=0,
-                               concat_axis=0, axis_index_groups=groups)
-        r_val = lax.all_to_all(send_val, st.axis_name, split_axis=0,
-                               concat_axis=0, axis_index_groups=groups)
-        r_scale = None
-        if scale is not None:
-            r_scale = lax.all_to_all(scale, st.axis_name, split_axis=0,
-                                     concat_axis=0, axis_index_groups=groups)
-        if wire != "raw":
-            # Every received row is a bucket for *this* device's subrange,
-            # whose base is e[j] with j = our position in the stage group
-            # (group members share identical stage-l edges).
-            j = (lax.axis_index(st.axis_name) // strides[l]) % st.degree
-            base = jnp.broadcast_to(e[j].astype(jnp.uint32), (st.degree,))
-            r_idx = _wc.unpack_indices(r_idx, base, st.bucket_capacity,
-                                       widths[l])
-        if merge in ("fused", "banded"):
-            from repro.kernels import ops as _kops
-            chunk, movf = _kops.merge_sorted_runs(
-                r_idx, r_val, st.merged_capacity, mode=merge,
-                row_scale=r_scale,
-                out_dtype=compute_dtype if wire != "raw" else None)
-            overflow = overflow + movf
-        else:
-            if r_scale is not None:
-                r_val = _wc.dequant8_rows(r_val, r_scale)
-            r_val = r_val.astype(compute_dtype)
-            cat = concat_sorted_groups(r_idx, r_val)
-            from .sparse_vec import compact_overflow
-            overflow = overflow + compact_overflow(cat, st.merged_capacity)
-            chunk = segment_compact(cat, st.merged_capacity,
-                                    use_kernel=use_kernel)
+        with scope(f"union/down{l}/bucket"):
+            buckets, ovf = bucket_partition(chunk, e, st.degree,
+                                            st.bucket_capacity)
+            overflow = overflow + ovf
+            scale = None
+            if wire == "raw":
+                send_idx, send_val = buckets.idx, buckets.val
+            else:
+                # Bucket d covers [e[d], e[d+1]); ship offsets from e[d].
+                send_idx = _wc.pack_indices(buckets.idx,
+                                            e[:st.degree].astype(jnp.uint32),
+                                            widths[l])
+                send_val = buckets.val
+                if wire == "delta+bf16":
+                    send_val = send_val.astype(jnp.bfloat16)
+                elif wire == "delta+int8ef":
+                    send_val, scale = _wc.quant8_rows(send_val)
+        with scope(f"union/down{l}/exchange"):
+            r_idx = lax.all_to_all(send_idx, st.axis_name, split_axis=0,
+                                   concat_axis=0, axis_index_groups=groups)
+            r_val = lax.all_to_all(send_val, st.axis_name, split_axis=0,
+                                   concat_axis=0, axis_index_groups=groups)
+            r_scale = None
+            if scale is not None:
+                r_scale = lax.all_to_all(scale, st.axis_name, split_axis=0,
+                                         concat_axis=0,
+                                         axis_index_groups=groups)
+            if wire != "raw":
+                # Every received row is a bucket for *this* device's
+                # subrange, whose base is e[j] with j = our position in the
+                # stage group (group members share identical stage-l edges).
+                j = (lax.axis_index(st.axis_name) // strides[l]) % st.degree
+                base = jnp.broadcast_to(e[j].astype(jnp.uint32), (st.degree,))
+                r_idx = _wc.unpack_indices(r_idx, base, st.bucket_capacity,
+                                           widths[l])
+        with scope(f"union/down{l}/merge"):
+            if merge in ("fused", "banded"):
+                from repro.kernels import ops as _kops
+                chunk, movf = _kops.merge_sorted_runs(
+                    r_idx, r_val, st.merged_capacity, mode=merge,
+                    row_scale=r_scale,
+                    out_dtype=compute_dtype if wire != "raw" else None)
+                overflow = overflow + movf
+            else:
+                if r_scale is not None:
+                    r_val = _wc.dequant8_rows(r_val, r_scale)
+                r_val = r_val.astype(compute_dtype)
+                cat = concat_sorted_groups(r_idx, r_val)
+                from .sparse_vec import compact_overflow
+                overflow = overflow + compact_overflow(cat, st.merged_capacity)
+                chunk = segment_compact(cat, st.merged_capacity,
+                                        use_kernel=use_kernel)
 
     # ---- up: allgather back through the same nodes (nested) ---------------
     for li in range(len(plan.stages) - 1, -1, -1):
         st = plan.stages[li]
         g = list(map(list, st.axis_index_groups))
-        if wire == "raw":
-            idx = lax.all_gather(chunk.idx, st.axis_name, axis_index_groups=g,
-                                 axis=0, tiled=True)
-            val = lax.all_gather(chunk.val, st.axis_name, axis_index_groups=g,
-                                 axis=0, tiled=True)
-        else:
-            # The sender's chunk covers its own stage-li subrange [e[j],
-            # e[j+1]); after the gather, row t covers subrange t of the
-            # group-shared edges, so both bases are static knowledge.
-            k = st.degree
-            e = edges[li].reshape((-1,))[-(k + 1):]
-            j = (lax.axis_index(st.axis_name) // strides[li]) % k
-            packed = _wc.pack_indices(chunk.idx[None, :],
-                                      e[j].astype(jnp.uint32)[None],
-                                      widths[li])[0]
-            words = lax.all_gather(packed, st.axis_name, axis_index_groups=g,
-                                   axis=0, tiled=True).reshape((k, -1))
-            idx = _wc.unpack_indices(words, e[:k].astype(jnp.uint32),
-                                     chunk.capacity, widths[li]
-                                     ).reshape((-1,))
-            if wire == "delta":
+        with scope(f"union/up{li}/gather"):
+            if wire == "raw":
+                idx = lax.all_gather(chunk.idx, st.axis_name,
+                                     axis_index_groups=g, axis=0, tiled=True)
                 val = lax.all_gather(chunk.val, st.axis_name,
                                      axis_index_groups=g, axis=0, tiled=True)
-            elif wire == "delta+bf16":
-                val = lax.all_gather(chunk.val.astype(jnp.bfloat16),
-                                     st.axis_name, axis_index_groups=g,
-                                     axis=0, tiled=True).astype(compute_dtype)
             else:
-                q, s = _wc.quant8_rows(chunk.val[None])
-                gq = lax.all_gather(q[0], st.axis_name, axis_index_groups=g,
-                                    axis=0, tiled=True)
-                gs = lax.all_gather(s, st.axis_name, axis_index_groups=g,
-                                    axis=0, tiled=True)        # [k] row scales
-                per = jnp.repeat(gs.astype(jnp.float32), chunk.capacity)
-                val = (gq.astype(jnp.float32)
-                       * per[(...,) + (None,) * (gq.ndim - 1)]
-                       ).astype(compute_dtype)
-        chunk = SparseChunk(idx=idx, val=val)  # concat of sorted disjoint ranges
+                # The sender's chunk covers its own stage-li subrange [e[j],
+                # e[j+1]); after the gather, row t covers subrange t of the
+                # group-shared edges, so both bases are static knowledge.
+                k = st.degree
+                e = edges[li].reshape((-1,))[-(k + 1):]
+                j = (lax.axis_index(st.axis_name) // strides[li]) % k
+                packed = _wc.pack_indices(chunk.idx[None, :],
+                                          e[j].astype(jnp.uint32)[None],
+                                          widths[li])[0]
+                words = lax.all_gather(packed, st.axis_name,
+                                       axis_index_groups=g, axis=0,
+                                       tiled=True).reshape((k, -1))
+                idx = _wc.unpack_indices(words, e[:k].astype(jnp.uint32),
+                                         chunk.capacity, widths[li]
+                                         ).reshape((-1,))
+                if wire == "delta":
+                    val = lax.all_gather(chunk.val, st.axis_name,
+                                         axis_index_groups=g, axis=0,
+                                         tiled=True)
+                elif wire == "delta+bf16":
+                    val = lax.all_gather(chunk.val.astype(jnp.bfloat16),
+                                         st.axis_name, axis_index_groups=g,
+                                         axis=0, tiled=True
+                                         ).astype(compute_dtype)
+                else:
+                    q, s = _wc.quant8_rows(chunk.val[None])
+                    gq = lax.all_gather(q[0], st.axis_name,
+                                        axis_index_groups=g, axis=0,
+                                        tiled=True)
+                    gs = lax.all_gather(s, st.axis_name, axis_index_groups=g,
+                                        axis=0, tiled=True)  # [k] row scales
+                    per = jnp.repeat(gs.astype(jnp.float32), chunk.capacity)
+                    val = (gq.astype(jnp.float32)
+                           * per[(...,) + (None,) * (gq.ndim - 1)]
+                           ).astype(compute_dtype)
+            # concat of sorted disjoint ranges
+            chunk = SparseChunk(idx=idx, val=val)
 
     # Trim/pad to the advertised out capacity (sorted already).
     if chunk.capacity != plan.out_capacity:
-        chunk = _trim_sorted(chunk, plan.out_capacity)
+        with scope("union/trim"):
+            chunk = _trim_sorted(chunk, plan.out_capacity)
     return chunk, overflow
 
 

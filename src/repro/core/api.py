@@ -43,6 +43,7 @@ from .netmodel import EC2_2013, Fabric
 from .sparse_vec import HashPerm
 from .simulator import ReduceStats, SimSparseAllreduce
 from .topology import ButterflyPlan, check_wire, tune
+from repro import obs
 
 
 class SparseAllreduce:
@@ -143,8 +144,11 @@ class SparseAllreduce:
         # a "hit" reuses a compiled union pipeline from _union_cache, a
         # "miss" plans + traces a new one.  Cumulative over the instance
         # lifetime (reconfig_dead clears the cache, so calls after it
-        # miss again until re-trace).
-        self.union_plan_stats = {"hits": 0, "misses": 0}
+        # miss again until re-trace).  "slots_received" adds, at every
+        # call, the rows one node receives from other nodes as the plan's
+        # capacities fix them (``DevicePlan.slots_received``).
+        self.union_plan_stats = {"hits": 0, "misses": 0,
+                                 "slots_received": 0}
         self._staging = None
         self._stage_rows = self._stage_cols = None
         self._first_alive = None
@@ -380,31 +384,61 @@ class SparseAllreduce:
         and compiled pipeline are cached per (shape, out_capacity,
         use_kernel, dead), so repeated same-shape calls pay tracing once.
         """
-        import jax
         import jax.numpy as jnp
 
-        from .allreduce import make_device_plan, run_union_allreduce
         from .replication import contribution_weights, first_alive_replicas
-        from repro.launch.mesh import make_mesh
-        r, m_phys = self.replication, self.num_physical
-        if r != 1 or self.dead:
-            contribution_weights(m_phys, r, self.dead)  # DeadLogicalNode
-        idx = jnp.asarray(idx)
-        val = jnp.asarray(val)
-        if idx.shape[0] != self.num_nodes:
-            raise ValueError(
-                f"union_reduce: expected {self.num_nodes} logical chunks, "
-                f"got {idx.shape[0]}")
-        if r > 1:
-            idx = jnp.tile(idx, (r,) + (1,) * (idx.ndim - 1))
-            val = jnp.tile(val, (r,) + (1,) * (val.ndim - 1))
-        key = (idx.shape, val.shape, val.dtype, out_capacity, use_kernel,
-               frozenset(self.dead or ()), self.wire)
-        fn = self._union_cache.get(key)
-        if fn is not None:
+        with obs.span("repro.union_reduce"):
+            r, m_phys = self.replication, self.num_physical
+            if r != 1 or self.dead:
+                contribution_weights(m_phys, r, self.dead)  # DeadLogicalNode
+            idx = jnp.asarray(idx)
+            val = jnp.asarray(val)
+            if idx.shape[0] != self.num_nodes:
+                raise ValueError(
+                    f"union_reduce: expected {self.num_nodes} logical "
+                    f"chunks, got {idx.shape[0]}")
+            fn, slots = self._union_entry(idx, val, out_capacity, use_kernel)
+            self.union_plan_stats["slots_received"] += slots
+            if r > 1:
+                idx = jnp.tile(idx, (r,) + (1,) * (idx.ndim - 1))
+                val = jnp.tile(val, (r,) + (1,) * (val.ndim - 1))
+            with obs.span("repro.union_reduce.launch"):
+                oi, ov, ovf = fn(idx, val)
+            if r > 1:
+                fa = first_alive_replicas(m_phys, r, self.dead)
+                oi, ov, ovf = oi[fa], ov[fa], ovf[fa]
+            return oi, ov, ovf
+
+    def union_fn(self, idx, val, out_capacity: int,
+                 use_kernel: bool = False):
+        """The cached jitted pipeline that :meth:`union_reduce` calls for
+        logical inputs shaped like ``idx`` / ``val`` (arrays or
+        ``jax.ShapeDtypeStruct``s), resolving it as a call would (a plan
+        hit or miss in ``union_plan_stats``).  It takes the physical inputs
+        (``[num_nodes * replication, C(,W)]``, replicas tiled) and returns
+        ``(idx, val, overflow)`` for every physical node; its
+        ``.lower(...).compile().as_text()`` is the program the calls run,
+        the way :meth:`repro.graph.engine.GraphEngine.run_fn` gives the
+        engine's."""
+        return self._union_entry(idx, val, out_capacity, use_kernel)[0]
+
+    def _union_entry(self, idx, val, out_capacity, use_kernel):
+        """``(jitted pipeline, rows a node receives per call)`` for logical
+        inputs shaped like ``idx`` / ``val``, from the cache or planned and
+        cached (a miss)."""
+        key = (tuple(idx.shape), tuple(val.shape), val.dtype, out_capacity,
+               use_kernel, frozenset(self.dead or ()), self.wire)
+        hit = self._union_cache.get(key)
+        if hit is not None:
             self.union_plan_stats["hits"] += 1
-        else:
-            self.union_plan_stats["misses"] += 1
+            return hit
+        self.union_plan_stats["misses"] += 1
+        with obs.span("repro.union_reduce.plan"):
+            import jax
+
+            from .allreduce import make_device_plan, run_union_allreduce
+            from repro.launch.mesh import make_mesh
+            r, m_phys = self.replication, self.num_physical
             mesh = self.mesh
             if mesh is None:
                 mesh = make_mesh((m_phys,), ("nodes",))
@@ -413,15 +447,16 @@ class SparseAllreduce:
                 [(axis, m_phys)], {axis: self.plan.degrees},
                 in_capacity=idx.shape[1], out_capacity=out_capacity,
                 replication=r)
-            fn = jax.jit(lambda i, v: run_union_allreduce(
-                mesh, dplan, i, v, use_kernel=use_kernel, merge=self.merge,
-                dead=self.dead, wire=self.wire))
-            self._union_cache[key] = fn
-        oi, ov, ovf = fn(idx, val)
-        if r > 1:
-            fa = first_alive_replicas(m_phys, r, self.dead)
-            oi, ov, ovf = oi[fa], ov[fa], ovf[fa]
-        return oi, ov, ovf
+            merge, dead, wire = self.merge, self.dead, self.wire
+
+            def union_allreduce(i, v):
+                return run_union_allreduce(mesh, dplan, i, v,
+                                           use_kernel=use_kernel, merge=merge,
+                                           dead=dead, wire=wire)
+
+            entry = (jax.jit(union_allreduce), dplan.slots_received)
+            self._union_cache[key] = entry
+        return entry
 
     # ------------------------------------------------------------------
     # Plan-reuse hooks (device backend).  :meth:`reduce` pays one host

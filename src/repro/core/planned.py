@@ -27,6 +27,7 @@ from .allreduce import DevicePlan
 from .sparse_vec import HashPerm
 from .simulator import SimSparseAllreduce
 from .topology import ButterflyPlan
+from repro.obs import scope
 
 
 def _pad_gather(rows: List[np.ndarray], width: int) -> np.ndarray:
@@ -206,15 +207,18 @@ class PlannedSparseAllreduce:
         for l, L in enumerate(self.layers):
             send_g, merge_s, _up_g, _up_s = per_layer[l]
             k, cap = send_g.shape[0], send_g.shape[1]
-            safe = jnp.maximum(send_g, 0)
-            picked = cur[safe] * (send_g >= 0)[(...,) + (None,) * (values.ndim - 1)]
             g = list(map(list, stages[l].axis_index_groups))
-            recv = lax.all_to_all(picked, stages[l].axis_name, split_axis=0,
-                                  concat_axis=0, axis_index_groups=g)
-            nxt = zeros(L.merged_size + 1)
-            nxt = nxt.at[merge_s.reshape((-1,))].add(
-                recv.reshape((k * cap,) + recv.shape[2:]))
-            cur = nxt[:-1]
+            with scope(f"planned/down{l}"):
+                safe = jnp.maximum(send_g, 0)
+                picked = cur[safe] * (send_g >= 0)[
+                    (...,) + (None,) * (values.ndim - 1)]
+                recv = lax.all_to_all(picked, stages[l].axis_name,
+                                      split_axis=0, concat_axis=0,
+                                      axis_index_groups=g)
+                nxt = zeros(L.merged_size + 1)
+                nxt = nxt.at[merge_s.reshape((-1,))].add(
+                    recv.reshape((k * cap,) + recv.shape[2:]))
+                cur = nxt[:-1]
 
         return cur[jnp.maximum(bottom_gather, 0)] \
             * bottom_hit[(...,) + (None,) * (values.ndim - 1)]
@@ -235,16 +239,17 @@ class PlannedSparseAllreduce:
         for l in reversed(range(len(self.layers))):
             _send_g, _merge_s, up_g, up_s = per_layer[l]
             k, cap = up_g.shape[0], up_g.shape[1]
-            safe = jnp.maximum(up_g, 0)
-            picked = up[safe] * (up_g >= 0)[(...,) + (None,) * (ndim - 1)]
             g = list(map(list, self.dplan.stages[l].axis_index_groups))
-            recv = lax.all_to_all(picked, self.dplan.stages[l].axis_name,
-                                  split_axis=0, concat_axis=0,
-                                  axis_index_groups=g)
-            nxt = zeros(self.layers[l].up_size + 1)
-            nxt = nxt.at[up_s.reshape((-1,))].set(
-                recv.reshape((k * cap,) + recv.shape[2:]), mode="drop")
-            up = nxt[:-1]
+            with scope(f"planned/up{l}"):
+                safe = jnp.maximum(up_g, 0)
+                picked = up[safe] * (up_g >= 0)[(...,) + (None,) * (ndim - 1)]
+                recv = lax.all_to_all(picked, self.dplan.stages[l].axis_name,
+                                      split_axis=0, concat_axis=0,
+                                      axis_index_groups=g)
+                nxt = zeros(self.layers[l].up_size + 1)
+                nxt = nxt.at[up_s.reshape((-1,))].set(
+                    recv.reshape((k * cap,) + recv.shape[2:]), mode="drop")
+                up = nxt[:-1]
 
         return up[jnp.maximum(user_gather, 0)] \
             * (user_gather >= 0)[(...,) + (None,) * (ndim - 1)]

@@ -40,8 +40,10 @@ from typing import Any, Callable, Dict, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import SparseAllreduce
 from repro.core.netmodel import EC2_2013, Fabric
+from repro.obs import scope
 
 
 # ---------------------------------------------------------------------------
@@ -100,12 +102,13 @@ def ell_matvec(cols, wts, x):
     there is no Pallas form of this product.
     """
     import jax.numpy as jnp
-    safe = jnp.maximum(cols, 0)
-    g = x[safe]                                  # [R, K] or [R, K, W]
-    mask = (cols >= 0).astype(x.dtype)
-    if x.ndim == 1:
-        return jnp.sum(wts * mask * g, axis=1)
-    return jnp.sum((wts * mask)[..., None] * g, axis=1)
+    with scope("ell_matvec"):
+        safe = jnp.maximum(cols, 0)
+        g = x[safe]                              # [R, K] or [R, K, W]
+        mask = (cols >= 0).astype(x.dtype)
+        if x.ndim == 1:
+            return jnp.sum(wts * mask * g, axis=1)
+        return jnp.sum((wts * mask)[..., None] * g, axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -178,14 +181,16 @@ class GraphEngine:
         self.seed = seed
         self.fabric = fabric
         self.plan_cache_arg = plan_cache
-        self.ar = SparseAllreduce(self.num_nodes, degrees, backend="device",
-                                  mesh=mesh, seed=seed, fabric=fabric,
-                                  value_width=app.value_width,
-                                  plan_cache=plan_cache, retune=retune)
-        self.config_stats = self.ar.config(self.out_sets, self.in_sets)
-        self.config_cache = self.ar.config_cache
-        self.planned, self.mesh = self.ar.planned_parts()
-        meta = self.ar.staging_metadata()
+        with obs.span("repro.engine.config"):
+            self.ar = SparseAllreduce(self.num_nodes, degrees,
+                                      backend="device", mesh=mesh, seed=seed,
+                                      fabric=fabric,
+                                      value_width=app.value_width,
+                                      plan_cache=plan_cache, retune=retune)
+            self.config_stats = self.ar.config(self.out_sets, self.in_sets)
+            self.config_cache = self.ar.config_cache
+            self.planned, self.mesh = self.ar.planned_parts()
+            meta = self.ar.staging_metadata()
         self.u_cap: int = meta["u_cap"]
         self.uin_cap: int = meta["uin_cap"]
         self.out_lens = meta["out_lens"]
@@ -279,25 +284,33 @@ class GraphEngine:
         def pre_body(state, extras, *routing):
             # round 1: SpMV + bottom half, issued before the scan starts
             s, e = unsq(state), unsq(extras)
-            out = app.out_fn(s, e)
-            bottom = planned.reduce_down_on_device(out, *routing)
+            with scope("engine/out"):
+                out = app.out_fn(s, e)
+            with scope("engine/reduce"):
+                bottom = planned.reduce_down_on_device(out, *routing)
             return resq(bottom), resq(out)
 
         def mid_body(state, bottom, extras, *routing):
             # round j's top-half return + round j+1's SpMV and down half
             self.report["step_traces"] += 1
             s, b, e = unsq(state), unsq(bottom), unsq(extras)
-            in_raw = planned.reduce_up_on_device(b, *routing)
-            s2 = app.update_fn(s, in_raw, e, axis)
-            out = app.out_fn(s2, e)
-            b2 = planned.reduce_down_on_device(out, *routing)
+            with scope("engine/reduce"):
+                in_raw = planned.reduce_up_on_device(b, *routing)
+            with scope("engine/update"):
+                s2 = app.update_fn(s, in_raw, e, axis)
+            with scope("engine/out"):
+                out = app.out_fn(s2, e)
+            with scope("engine/reduce"):
+                b2 = planned.reduce_down_on_device(out, *routing)
             return resq(s2), resq(b2), resq(out)
 
         def post_body(state, bottom, extras, *routing):
             # round k: top-half return + update, after the scan drains
             s, b, e = unsq(state), unsq(bottom), unsq(extras)
-            in_raw = planned.reduce_up_on_device(b, *routing)
-            return resq(app.update_fn(s, in_raw, e, axis))
+            with scope("engine/reduce"):
+                in_raw = planned.reduce_up_on_device(b, *routing)
+            with scope("engine/update"):
+                return resq(app.update_fn(s, in_raw, e, axis))
 
         rspecs = (spec,) * len(self._routing)
         smap_pre = shard_map(pre_body, mesh=self.mesh,
@@ -352,9 +365,12 @@ class GraphEngine:
             self.report["step_traces"] += 1
             s = tree_map(lambda a: a.reshape(a.shape[1:]), state)
             e = tree_map(lambda a: a.reshape(a.shape[1:]), extras)
-            out = app.out_fn(s, e)
-            in_raw = planned.reduce_on_device(out, *routing)
-            s2 = app.update_fn(s, in_raw, e, axis)
+            with scope("engine/out"):
+                out = app.out_fn(s, e)
+            with scope("engine/reduce"):
+                in_raw = planned.reduce_on_device(out, *routing)
+            with scope("engine/update"):
+                s2 = app.update_fn(s, in_raw, e, axis)
             return (tree_map(lambda a: a.reshape((1,) + a.shape), s2),
                     out.reshape((1,) + out.shape))
 
@@ -424,13 +440,16 @@ class GraphEngine:
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
         from jax.tree_util import tree_map
-        fn = self.run_fn(k, collect)
-        # host arrays go straight to their shards: staged whole on one
-        # device first, the stacked ELL tables of M partitions do not fit
-        shard = NamedSharding(self.mesh, P(self.axis))
-        state, extras = jax.device_put(
-            (state, extras if extras is not None else {}), shard)
-        final, last_out, traj = fn(state, extras, *self._routing)
-        self.report["dispatches"] += 1
-        self.report["rounds"] += k
-        return final, last_out, traj
+        with obs.span("repro.engine.run"):
+            fn = self.run_fn(k, collect)
+            # host arrays go straight to their shards: staged whole on one
+            # device first, the stacked ELL tables of M partitions do not fit
+            shard = NamedSharding(self.mesh, P(self.axis))
+            with obs.span("repro.engine.stage"):
+                state, extras = jax.device_put(
+                    (state, extras if extras is not None else {}), shard)
+            with obs.span("repro.engine.launch"):
+                final, last_out, traj = fn(state, extras, *self._routing)
+            self.report["dispatches"] += 1
+            self.report["rounds"] += k
+            return final, last_out, traj

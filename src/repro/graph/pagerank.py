@@ -15,6 +15,7 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import SparseAllreduce
 from repro.core.netmodel import EC2_2013, Fabric
 from repro.data.pipeline import random_edge_partition
@@ -49,19 +50,21 @@ class Partition:
 
 def build_partitions(edges: np.ndarray, n_vertices: int, m: int,
                      seed: int = 0) -> List[Partition]:
-    outdeg = np.bincount(edges[:, 0], minlength=n_vertices).astype(np.float64)
-    outdeg[outdeg == 0] = 1.0
-    parts = []
-    for e in random_edge_partition(edges, m, seed=seed):
-        src, dst = e[:, 0], e[:, 1]
-        in_idx = np.unique(src)
-        out_idx = np.unique(dst)
-        parts.append(Partition(
-            src=src, dst=dst, in_idx=in_idx, out_idx=out_idx,
-            src_pos=np.searchsorted(in_idx, src),
-            dst_pos=np.searchsorted(out_idx, dst),
-            inv_outdeg=1.0 / outdeg[src]))
-    return parts
+    with obs.span("repro.graph.build_partitions"):
+        outdeg = np.bincount(edges[:, 0],
+                             minlength=n_vertices).astype(np.float64)
+        outdeg[outdeg == 0] = 1.0
+        parts = []
+        for e in random_edge_partition(edges, m, seed=seed):
+            src, dst = e[:, 0], e[:, 1]
+            in_idx = np.unique(src)
+            out_idx = np.unique(dst)
+            parts.append(Partition(
+                src=src, dst=dst, in_idx=in_idx, out_idx=out_idx,
+                src_pos=np.searchsorted(in_idx, src),
+                dst_pos=np.searchsorted(out_idx, dst),
+                inv_outdeg=1.0 / outdeg[src]))
+        return parts
 
 
 def pagerank(edges: np.ndarray, n_vertices: int, m: int,
@@ -136,11 +139,12 @@ def pagerank_state(parts: List[Partition], n_vertices: int,
     """Stacked ELL extras + the uniform initial state for a PageRank run
     over ``parts``, sized to an engine's frozen ``u_cap`` / ``uin_cap``."""
     from . import engine as eng
-    cols, wts = eng.stack_ell([p.ell_tables() for p in parts], u_cap)
-    p0 = np.zeros((len(parts), uin_cap), np.float32)
-    for i, p in enumerate(parts):
-        p0[i, : len(p.in_idx)] = 1.0 / n_vertices
-    return {"cols": cols, "wts": wts}, p0
+    with obs.span("repro.graph.ell_tables"):
+        cols, wts = eng.stack_ell([p.ell_tables() for p in parts], u_cap)
+        p0 = np.zeros((len(parts), uin_cap), np.float32)
+        for i, p in enumerate(parts):
+            p0[i, : len(p.in_idx)] = 1.0 / n_vertices
+        return {"cols": cols, "wts": wts}, p0
 
 
 def make_pagerank_engine(parts: List[Partition], n_vertices: int,
