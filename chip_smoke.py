@@ -172,8 +172,9 @@ def _chung_lu(n: int, alpha: float, edge_factor: int, seed: int):
 
 
 def _ell_shape(edges, n: int):
-    """(R, K) of a single partition's ELL tables: rows written, max
-    in-degree — what ``build_ell`` allocates."""
+    """(R, K) of a single partition's plain ELL tables: rows written, max
+    in-degree — what ``build_ell`` allocates (the engine's degree-sliced
+    tables, ``ell_extras``, hold far fewer slots on a hub-heavy graph)."""
     import numpy as np
     dst = edges[:, 1]
     rows = len(np.unique(dst))

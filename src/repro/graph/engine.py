@@ -28,10 +28,14 @@ oracle.  Replication is not plumbed through the engine yet — construct it
 unreplicated (the planned path underneath does support r-way replication
 for per-call reduces).
 
-Scaling caveat: the stacked ELL tables pad every partition to the global
-max rows × max per-row nonzeros.  The hash permutation balances *columns*
-(that is the paper's point), not row degrees — power-law hub rows inflate
-``K``; a segmented-CSR kernel is the planned fix for hub-heavy partitions.
+ELL layout (:func:`ell_extras`): degree-sliced.  The hash permutation
+balances *columns* (that is the paper's point), not row degrees, so one
+table padded to the widest row would, on a power-law graph, pad every row
+to the hub and gather rows × K slots instead of the edges.  Instead each
+partition's output rows are grouped by in-degree class ⌈log2 deg⌉ and
+each class is padded only to its own widest row; one gather puts the
+class results back in row order.  A near-regular graph has few classes
+and gathers about its edges plus one slot a row.
 """
 from __future__ import annotations
 
@@ -47,8 +51,38 @@ from repro.obs import scope
 
 
 # ---------------------------------------------------------------------------
-# Vectorized ELL construction (shared with Partition.ell_tables)
+# Vectorized ELL construction
 # ---------------------------------------------------------------------------
+
+def _stable_argsort(keys: np.ndarray) -> np.ndarray:
+    """``np.argsort(keys, kind="stable")`` of non-negative integers, one
+    16-bit digit at a time from the lowest: numpy sorts 16-bit keys by
+    radix in linear time and wider ones by merge sort, which took 2.4×
+    as long on the 33M row ids of a 2^21-vertex graph."""
+    order = np.argsort((keys & 0xFFFF).astype(np.uint16), kind="stable")
+    top, shift = int(keys.max(initial=0)), 16
+    while top >> shift:
+        digit = ((keys[order] >> shift) & 0xFFFF).astype(np.uint16)
+        order = order[np.argsort(digit, kind="stable")]
+        shift += 16
+    return order
+
+
+def _row_slots(rows: np.ndarray, n_rows: int):
+    """Group COO entries by row, keeping their order within each row.
+
+    Returns ``(order, r, slots, counts)``: ``order`` the stable argsort of
+    ``rows``, ``r = rows[order]``, ``slots`` each sorted entry's offset
+    from its row's start (``arange(E) - row_start[row]``) and ``counts``
+    the entries per row."""
+    order = _stable_argsort(rows)
+    r = rows[order]
+    counts = np.bincount(r, minlength=n_rows)
+    starts = np.zeros(len(counts) + 1, np.int64)
+    np.cumsum(counts, out=starts[1:])
+    slots = np.arange(len(r), dtype=np.int64) - starts[r]
+    return order, r, slots, counts
+
 
 def build_ell(rows: np.ndarray, cols: np.ndarray, weights: np.ndarray,
               n_rows: int, min_k: int = 1
@@ -59,20 +93,14 @@ def build_ell(rows: np.ndarray, cols: np.ndarray, weights: np.ndarray,
     and column positions).  Returns ``(ell_cols int32, ell_wts float32)``
     with ``K = max(row_count, min_k)``; empty slots are ``-1`` / ``0``.
     Entries within a row keep their original (stable) edge order — the
-    same layout the old per-edge Python loop produced, without the loop:
-    a stable argsort groups rows, and each entry's slot is its offset from
-    its row's start (``arange(E) - row_start[row]``).
+    same layout the old per-edge Python loop produced, without the loop
+    (:func:`_row_slots`).
     """
     if n_rows == 0:
         return (np.full((0, min_k), -1, np.int32),
                 np.zeros((0, min_k), np.float32))
-    order = np.argsort(rows, kind="stable")
-    r = rows[order]
-    counts = np.bincount(r, minlength=n_rows)
+    order, r, slots, counts = _row_slots(rows, n_rows)
     kmax = max(int(counts.max(initial=0)), min_k)
-    starts = np.zeros(n_rows + 1, np.int64)
-    np.cumsum(counts, out=starts[1:])
-    slots = np.arange(len(r), dtype=np.int64) - starts[r]
     ell_cols = np.full((n_rows, kmax), -1, np.int32)
     ell_wts = np.zeros((n_rows, kmax), np.float32)
     ell_cols[r, slots] = np.asarray(cols)[order]
@@ -80,40 +108,113 @@ def build_ell(rows: np.ndarray, cols: np.ndarray, weights: np.ndarray,
     return ell_cols, ell_wts
 
 
-def stack_ell(tables, n_rows: int) -> Tuple[np.ndarray, np.ndarray]:
-    """Stack per-node ``build_ell`` outputs into ``[M, n_rows, K]`` tensors
-    (K = global max; rows/K padded with ``-1`` / ``0``) — the static
-    per-device extras the engine shards over the mesh."""
-    m = len(tables)
-    kmax = max(max(c.shape[1] for c, _ in tables), 1)
-    cols = np.full((m, n_rows, kmax), -1, np.int32)
-    wts = np.zeros((m, n_rows, kmax), np.float32)
-    for i, (c, w) in enumerate(tables):
-        cols[i, : c.shape[0], : c.shape[1]] = c
-        wts[i, : w.shape[0], : w.shape[1]] = w
-    return cols, wts
+def degree_class(counts: np.ndarray) -> np.ndarray:
+    """In-degree class ⌈log2 d⌉ of each row (``-1`` for an empty row):
+    the bit length of d - 1, so 1 → 0, 2 → 1, 3..4 → 2, 5..8 → 3."""
+    counts = np.asarray(counts, np.int64)
+    return np.where(counts > 0, np.frexp(np.maximum(counts - 1, 0))[1], -1)
 
 
-def ell_matvec(cols, wts, x):
-    """``y[r] = sum_k wts[r,k] * x[cols[r,k]]`` with ``cols < 0`` padding.
+def ell_extras(triplets, n_rows: int) -> dict:
+    """The degree-sliced ELL extras of M partitions' SpMVs (module
+    docstring).
 
-    ``x``: [N] or [N, W] (per-device state).  An XLA gather and a row sum;
-    the partition's ``x`` is far larger than a kernel's fast memory, so
-    there is no Pallas form of this product.
+    ``triplets``: per node ``(rows, cols, weights)``, [E_i] COO entries
+    with local row positions below ``n_rows`` (the engine's ``u_cap``).
+
+    Each node's nonempty rows are grouped by the global degree classes c
+    (:func:`degree_class`), in row order within a class; class c gets
+    ``[M, R_c, K_c]`` tables, R_c its most rows on any node and K_c its
+    widest row, empty slots ``-1`` / ``0``.  ``{"cols", "wts"}`` hold the
+    first class's tables, ``"more"`` a tuple of ``(cols, wts)`` for the
+    others, and ``"perm"`` ``[M, n_rows]`` int32 maps each output row to
+    its place in the concatenated class results (an empty row to the zero
+    after them).  Every leaf has the leading M axis the engine shards.  A
+    graph with no edges gets one class of no rows.
+    """
+    m = len(triplets)
+    counts = np.stack([np.bincount(np.asarray(r, np.int64), minlength=n_rows)
+                       for r, _, _ in triplets])           # [M, n_rows]
+    cls = degree_class(counts)
+    classes = np.unique(cls[cls >= 0])
+    if not len(classes):
+        classes = np.zeros(1, np.int64)
+    r_cap = np.array([(cls == c).sum(axis=1).max() for c in classes],
+                     np.int64)
+    k_cap = np.array([counts[cls == c].max(initial=1) for c in classes],
+                     np.int64)
+    # all class tables in one flat buffer: class j's [M, R_j, K_j] block
+    # starts at base[j], so every entry lands in one vectorized store
+    base = np.concatenate([[0], np.cumsum(m * r_cap * k_cap)])
+    flat_c = np.full(base[-1], -1, np.int32)
+    flat_w = np.zeros(base[-1], np.float32)
+    offsets = np.concatenate([[0], np.cumsum(r_cap)])
+    perm = np.full((m, n_rows), offsets[-1], np.int32)
+    for i, (rows, c, w) in enumerate(triplets):
+        order, r, slot, _ = _row_slots(np.asarray(rows), n_rows)
+        by_class = np.argsort(cls[i], kind="stable")      # row order kept
+        ci = cls[i, by_class]
+        pos = np.empty(n_rows, np.int64)                  # rank in class
+        pos[by_class] = np.arange(n_rows) - np.searchsorted(ci, ci)
+        j = np.searchsorted(classes, cls[i])              # class index
+        live = cls[i] >= 0
+        perm[i, live] = offsets[j[live]] + pos[live]
+        row_at = base[j] + (i * r_cap[j] + pos) * k_cap[j]  # a row's slot 0
+        at = row_at[r] + slot
+        flat_c[at] = np.asarray(c)[order]
+        flat_w[at] = np.asarray(w)[order]
+    tables = [(flat_c[base[j]:base[j + 1]].reshape(m, rc, kc),
+               flat_w[base[j]:base[j + 1]].reshape(m, rc, kc))
+              for j, (rc, kc) in enumerate(zip(r_cap, k_cap))]
+    return {"cols": tables[0][0], "wts": tables[0][1],
+            "more": tuple(tables[1:]), "perm": perm}
+
+
+def _ell_rows(cols, wts, x):
+    """``y[r] = sum_k wts[r,k] * x[cols[r,k]]`` over one table."""
+    import jax.numpy as jnp
+    safe = jnp.maximum(cols, 0)
+    g = x[safe]                              # [R, K] or [R, K, W]
+    mask = (cols >= 0).astype(x.dtype)
+    if x.ndim == 1:
+        return jnp.sum(wts * mask * g, axis=1)
+    return jnp.sum((wts * mask)[..., None] * g, axis=1)
+
+
+def ell_matvec(ell, x):
+    """``y[r] = sum_k wts[r,k] * x[cols[r,k]]`` with ``cols < 0`` padding,
+    over one device's :func:`ell_extras` (any further keys are ignored).
+
+    ``x``: [N] or [N, W] (per-device state).  A gather and row sum per
+    degree class, the results concatenated with a zero and gathered back
+    into row order by ``perm``.  The partition's ``x`` is far larger than
+    a kernel's fast memory, so there is no Pallas form of this product.
     """
     import jax.numpy as jnp
     with scope("ell_matvec"):
-        safe = jnp.maximum(cols, 0)
-        g = x[safe]                              # [R, K] or [R, K, W]
-        mask = (cols >= 0).astype(x.dtype)
-        if x.ndim == 1:
-            return jnp.sum(wts * mask * g, axis=1)
-        return jnp.sum((wts * mask)[..., None] * g, axis=1)
+        ys = [_ell_rows(c, w, x) for c, w in
+              ((ell["cols"], ell["wts"]),) + tuple(ell["more"])]
+        ys.append(jnp.zeros((1,) + x.shape[1:], x.dtype))
+        return jnp.concatenate(ys)[ell["perm"]]
 
 
 # ---------------------------------------------------------------------------
 # The engine
 # ---------------------------------------------------------------------------
+
+def _place(tree, shard):
+    """``jax.device_put(tree, shard)`` that passes over the leaves already
+    placed so: putting a placed array again costs the host tens of
+    microseconds a leaf, every dispatch, and the sliced ELL extras have
+    a pair of leaves per degree class."""
+    import jax
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    todo = [i for i, a in enumerate(leaves)
+            if not (isinstance(a, jax.Array) and a.sharding == shard)]
+    for i, a in zip(todo, jax.device_put([leaves[i] for i in todo], shard)):
+        leaves[i] = a
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
 
 @dataclasses.dataclass(frozen=True)
 class EngineApp:
@@ -446,7 +547,7 @@ class GraphEngine:
             # device first, the stacked ELL tables of M partitions do not fit
             shard = NamedSharding(self.mesh, P(self.axis))
             with obs.span("repro.engine.stage"):
-                state, extras = jax.device_put(
+                state, extras = _place(
                     (state, extras if extras is not None else {}), shard)
             with obs.span("repro.engine.launch"):
                 final, last_out, traj = fn(state, extras, *self._routing)

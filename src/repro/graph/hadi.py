@@ -105,7 +105,7 @@ def _hadi_device(parts, req, n_vertices: int, degrees, max_hops: int,
     m, w = len(parts), trials * bits
 
     def out_fn(s, e):
-        acc = eng.ell_matvec(e["cols"], e["wts"], s)
+        acc = eng.ell_matvec(e, s)
         import jax.numpy as jnp
         return jnp.minimum(acc, 1.0)
 
@@ -120,19 +120,16 @@ def _hadi_device(parts, req, n_vertices: int, degrees, max_hops: int,
         degrees=degrees, mesh=mesh, seed=seed)
     # edge (src, dst) contributes b[src] to row dst: cols = src position in
     # the request set, rows = dst position in out_idx, weight 1 (OR)
-    tables = [eng.build_ell(p.dst_pos,
-                            np.searchsorted(req[i], p.src),
-                            np.ones(len(p.src), np.float32),
-                            len(p.out_idx))
-              for i, p in enumerate(parts)]
-    cols, wts = eng.stack_ell(tables, engine.u_cap)
+    extras = eng.ell_extras(
+        [(p.dst_pos, np.searchsorted(req[i], p.src),
+          np.ones(len(p.src), np.float32)) for i, p in enumerate(parts)],
+        engine.u_cap)
 
     b0 = fm_bitstrings(n_vertices, bits, trials, rng)
     state0 = np.zeros((m, engine.uin_cap, w), np.float32)
     for i, r in enumerate(req):
         state0[i, : len(r)] = b0[r].reshape(len(r), w)
-    _, _, traj = engine.run(max_hops, state0, {"cols": cols, "wts": wts},
-                            collect="trajectory")
+    _, _, traj = engine.run(max_hops, state0, extras, collect="trajectory")
     traj = np.asarray(traj, np.float64)           # [hops, M, req_cap, w]
 
     b = b0.copy()

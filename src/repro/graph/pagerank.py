@@ -38,14 +38,22 @@ class Partition:
         np.add.at(out, self.dst_pos, in_values[self.src_pos] * self.inv_outdeg)
         return out
 
+    def ell_coo(self, weights: Optional[np.ndarray] = None
+                ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """``(rows, cols, weights)`` of this partition's SpMV: edge ``e``
+        adds ``in[src_pos[e]] * w[e]`` to row ``dst_pos[e]`` — what the
+        engine's ELL tables (``engine.ell_extras``) are built from."""
+        w = self.inv_outdeg if weights is None else weights
+        return self.dst_pos, self.src_pos, w
+
     def ell_tables(self, weights: Optional[np.ndarray] = None
                    ) -> Tuple[np.ndarray, np.ndarray]:
         """Padded ELL ``(cols, wts)`` of this partition's SpMV — vectorized
         (``engine.build_ell``: bincount/argsort, no per-edge Python loop);
-        the same construction the device engine stacks across nodes."""
+        one node's rows padded to its widest, where the engine's tables
+        (``engine.ell_extras``) pad each degree class to its own."""
         from .engine import build_ell
-        w = self.inv_outdeg if weights is None else weights
-        return build_ell(self.dst_pos, self.src_pos, w, len(self.out_idx))
+        return build_ell(*self.ell_coo(weights), len(self.out_idx))
 
 
 def build_partitions(edges: np.ndarray, n_vertices: int, m: int,
@@ -126,7 +134,7 @@ def make_pagerank_app(parts: List[Partition], n_vertices: int,
     from . import engine as eng
     app = eng.EngineApp(
         name="pagerank",
-        out_fn=lambda s, e: eng.ell_matvec(e["cols"], e["wts"], s),
+        out_fn=lambda s, e: eng.ell_matvec(e, s),
         update_fn=lambda s, in_raw, e, ax:
             (1.0 - damping) / n_vertices + damping * in_raw)
     return (app,
@@ -136,15 +144,16 @@ def make_pagerank_app(parts: List[Partition], n_vertices: int,
 
 def pagerank_state(parts: List[Partition], n_vertices: int,
                    u_cap: int, uin_cap: int):
-    """Stacked ELL extras + the uniform initial state for a PageRank run
-    over ``parts``, sized to an engine's frozen ``u_cap`` / ``uin_cap``."""
+    """ELL extras (``engine.ell_extras``) + the uniform initial state for
+    a PageRank run over ``parts``, sized to an engine's frozen ``u_cap`` /
+    ``uin_cap``."""
     from . import engine as eng
     with obs.span("repro.graph.ell_tables"):
-        cols, wts = eng.stack_ell([p.ell_tables() for p in parts], u_cap)
+        extras = eng.ell_extras([p.ell_coo() for p in parts], u_cap)
         p0 = np.zeros((len(parts), uin_cap), np.float32)
         for i, p in enumerate(parts):
             p0[i, : len(p.in_idx)] = 1.0 / n_vertices
-        return {"cols": cols, "wts": wts}, p0
+        return extras, p0
 
 
 def make_pagerank_engine(parts: List[Partition], n_vertices: int,

@@ -97,7 +97,7 @@ def _power_iteration_device(parts, n_vertices: int, degrees, iters: int,
     m = len(parts)
 
     def out_fn(s, e):
-        return eng.ell_matvec(e["cols"], e["wts"], s["v"])
+        return eng.ell_matvec(e, s["v"])
 
     def update_fn(s, in_raw, e, ax):
         import jax.numpy as jnp
@@ -114,7 +114,7 @@ def _power_iteration_device(parts, n_vertices: int, degrees, iters: int,
         [p.out_idx.astype(np.uint32) for p in parts],
         [p.in_idx.astype(np.uint32) for p in parts],
         app, degrees=degrees, mesh=mesh, seed=seed)
-    cols, wts = eng.stack_ell([p.ell_tables() for p in parts], engine.u_cap)
+    extras = eng.ell_extras([p.ell_coo() for p in parts], engine.u_cap)
 
     # ownership: vertex counted at the first node (in index order) whose
     # in-set requests it — mirrors the sim's first-writer-wins assembly
@@ -132,8 +132,7 @@ def _power_iteration_device(parts, n_vertices: int, degrees, iters: int,
     for i, p in enumerate(parts):
         v0[i, : len(p.in_idx)] = v[p.in_idx]
     state0 = {"v": v0, "lam": np.zeros((m, 1), np.float32)}
-    final, _, _ = engine.run(iters, state0,
-                             {"cols": cols, "wts": wts, "norm_w": norm_w})
+    final, _, _ = engine.run(iters, state0, dict(extras, norm_w=norm_w))
     v_dev = np.asarray(final["v"], np.float64)
     lam = float(np.asarray(final["lam"])[0, 0])
 
